@@ -1,10 +1,12 @@
 """Benchmark utilities: timing, scaled datasets, index builders.
 
-Scaling note (DESIGN.md §8): this container is a single CPU core, so the
-paper's 1M-10B vector datasets are reproduced at 10^4-10^5 scale with the
-same methodology; we report absolute numbers for this platform plus the
-RATIOS vs baselines, which are the paper's claims (O(1) vs O(N), constant
-vs linear scaling, recall parity).
+Scale note: these benchmarks reproduce the paper's 1M-10B vector
+experiments at 10^4-10^5 vectors with the same methodology, so they run
+on any JAX backend in minutes. Their times are those of the backend they
+ran on (a CPU run says nothing about a TPU), and what carries over between
+platforms is their ratios against the baselines (O(1) vs O(N), constant vs
+linear scaling) and their recall. ``chip_smoke.py`` at the repository
+root drives the served path on a TPU at SIFT1M scale.
 """
 from __future__ import annotations
 

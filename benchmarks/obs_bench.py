@@ -22,8 +22,8 @@ toggling ``Telemetry.enabled`` between interleaved blocks:
     influence. Closed loop (one request in flight), so queueing
     amplification cannot multiply scheduler noise into the tail.
   * **In-bench gate.** The bench asserts pooled p99 ratio <= 1.05; a
-    violation raises, which ``benchmarks/run.py --strict`` turns into a
-    non-zero CI exit. ``scripts/check_bench.py`` additionally bands the
+    violation raises, which ``benchmarks/run.py`` turns into a non-zero
+    exit. ``scripts/check_bench.py`` additionally bands the
     recorded ratio against the committed baseline so the gate itself
     cannot be silently loosened.
 

@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV and writes
 experiments/bench_results.json. Run: PYTHONPATH=src python -m benchmarks.run
-[names ...] [--only fig1a,...] [--skip-dist] [--deferred] [--strict]
+[names ...] [--only fig1a,...] [--skip-dist] [--deferred]
 
 ``streaming_churn --deferred`` runs the eager AND deferred churn variants
 back-to-back and records p50/p99 latencies + jit compile counts to
@@ -31,15 +31,18 @@ in-bench) to ``BENCH_drift.json`` (the slow CI job's perf data points —
 ``scripts/check_bench.py`` gates them against committed baselines).
 
 Exceptions inside one benchmark print a ``<name>.ERROR`` row and the run
-continues, so a multi-artifact sweep survives a single failure;
-``--strict`` additionally exits non-zero at the end if *any* artifact
-errored (CI uses it so a typo'd registry name or a swallowed exception
-can't pass silently).
+continues, so a multi-artifact sweep survives a single failure; the
+harness then exits non-zero at the end if *any* artifact errored, so a
+swallowed exception never passes as a result.
+
+If ``JAX_COMPILATION_CACHE_DIR`` is unset, compiled programs are cached in
+``<repo>/.jax_cache``.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -73,8 +76,8 @@ def run_summary_artifact(name: str, fn, bench_path: str, results: dict
                          ) -> None:
     """Run a (rows, summary) benchmark, print rows, record results, and
     write the summary JSON next to the repo root (the slow CI job uploads
-    it). Errors are swallowed like the generic loop — CI must check for
-    the ``<name>.ERROR`` row / a fresh artifact, not the exit code."""
+    it). Errors are recorded like the generic loop and fail the run at
+    its end."""
     t0 = time.time()
     try:
         rows, summary = fn()
@@ -101,10 +104,12 @@ def main() -> None:
     ap.add_argument("--deferred", action="store_true",
                     help="run streaming_churn in eager+deferred comparison "
                          "mode and write BENCH_streaming_churn.json")
-    ap.add_argument("--strict", action="store_true",
-                    help="still record every row, but exit non-zero if any "
-                         "artifact errored (CI regression safety)")
     args = ap.parse_args()
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          str(Path(__file__).resolve().parent.parent
+                              / ".jax_cache"))
     only = set(args.only.split(",")) if args.only else set()
     only |= set(args.names)
     only = only or None
@@ -196,8 +201,7 @@ def main() -> None:
                      if isinstance(v, dict) and "error" in v)
     if errored:
         print(f"# errored artifacts: {','.join(errored)}")
-        if args.strict:
-            sys.exit(1)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

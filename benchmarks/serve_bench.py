@@ -15,8 +15,8 @@ scheme of the parquet-aggregator benchmark plan (SNIPPETS.md §2):
   * **SLO gate.** The bench itself asserts p99(active) <= 5x p99(idle)
     at every scale point (the paper's search-during-ingest claim) and
     that jit executable counts stay within the engine's coalescing
-    bound. A violation raises, which ``benchmarks/run.py --strict``
-    turns into a non-zero exit for CI.
+    bound. A violation raises, which ``benchmarks/run.py`` turns into a
+    non-zero exit.
 
 Writes ``BENCH_serve.json`` via ``benchmarks/run.py serve_churn``, plus
 ``TELEMETRY_serve.json`` — the engine's end-of-run JSON telemetry

@@ -132,7 +132,7 @@ def tiered_sweep_summary():
         rows.append(row)
         ratios[key] = point
     bad = [k for k, p in ratios.items() if p["parity"] != 1.0]
-    if bad:        # --strict turns this into a non-zero CI exit
+    if bad:        # benchmarks/run.py turns this into a non-zero exit
         raise AssertionError(
             f"tiered search diverged from the all-resident pool at "
             f"{','.join(bad)} — residency bug")

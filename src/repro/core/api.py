@@ -91,7 +91,7 @@ from typing import Iterator, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import filters as flt
 from repro.core import index as ix
@@ -437,6 +437,18 @@ def _mesh_ops(cfg: SIVFConfig, mesh: Mesh, axis: str, impl: str,
     raw_search = dist.sharded_search(cfg, mesh, axis, impl, block_q,
                                      use_tables)
 
+    def sharded(st):
+        # keep every leaf on the shard axis: XLA would otherwise return the
+        # zero-width planes and the fresh error vector replicated, and the
+        # next call would compile again for the changed input shardings
+        return jax.tree.map(lambda x: jax.lax.with_sharding_constraint(
+            x, dist.stack_sharding(x, mesh, axis)), st)
+
+    def replicated(x):
+        # the aux scalars are replicated; a sharded [S] vector beside them
+        # cannot be packed into _resolve_aux's one transfer
+        return jax.sharding.reshard(x, NamedSharding(mesh, P()))
+
     def _presence(state, ids, valid):
         # an id lives only on its owner shard: gather that shard's ATT row
         # (mask before indexing — see the single-backend note)
@@ -457,7 +469,7 @@ def _mesh_ops(cfg: SIVFConfig, mesh: Mesh, axis: str, impl: str,
         out = raw_insert(_clear_error(state), vecs, ids, attrs)
         st, plan = out if cfg.tiered else (out, None)
         aux["errors"] = _or_bits(st.error)
-        aux["shard_errors"] = st.error                       # [S] bits
+        aux["shard_errors"] = replicated(st.error)           # [S] bits
         aux["n_live_after"] = jnp.sum(st.n_live)
         # partial per-shard failure: only ids on committing shards count
         # as overwritten — an aborting shard restored its old payloads
@@ -465,18 +477,18 @@ def _mesh_ops(cfg: SIVFConfig, mesh: Mesh, axis: str, impl: str,
         failed = shard_failed[jnp.where(valid, ids % n, 0)]
         aux["n_overwritten"] = _count_unique(ids, pb & ~failed)
         if cfg.tiered:     # stacked [S, B] plan for the per-shard replay
-            return _clear_error(st), aux, plan
-        return _clear_error(st), aux
+            return sharded(_clear_error(st)), aux, plan
+        return sharded(_clear_error(st)), aux
 
     @partial(jax.jit, donate_argnums=(0,))
     def delete_fn(state, ids):
         _, _, aux = _pre(state, ids)
         st = raw_delete(_clear_error(state), ids)
         aux["errors"] = _or_bits(st.error)
-        aux["shard_errors"] = st.error
+        aux["shard_errors"] = replicated(st.error)
         aux["n_live_after"] = jnp.sum(st.n_live)
         aux["n_overwritten"] = jnp.zeros((), jnp.int32)
-        return _clear_error(st), aux
+        return sharded(_clear_error(st)), aux
 
     @partial(jax.jit, static_argnums=(2, 3, 4))
     def search_fn(state, queries, k, nprobe, fstruct, fconsts):
@@ -491,13 +503,18 @@ def _mesh_ops(cfg: SIVFConfig, mesh: Mesh, axis: str, impl: str,
 # The handle
 # ---------------------------------------------------------------------------
 
-def _resolve_backend(backend, axis: str) -> tuple[str, int]:
-    """Validate a backend spec -> (``"single"`` | ``"mesh"``, shard count).
+def _resolve_backend(backend, axis: str) -> tuple[str, int, object]:
+    """Validate a backend spec -> (``"single"`` | ``"mesh"``, shard count,
+    backend).
 
     The single point of truth for what a backend argument may be
     (:class:`Index` construction, :meth:`Index.load`,
     :meth:`Index.reshard` all accept the same forms) — a mesh must carry
     the index's data axis, anything else must be the literal ``"single"``.
+    A mesh comes back over the same devices with ``Auto`` axis types:
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the
+    index's gathers that mix the sharded state with replicated ids would
+    each need an explicit output sharding.
     """
     if isinstance(backend, Mesh):
         if axis not in backend.shape:
@@ -505,11 +522,14 @@ def _resolve_backend(backend, axis: str) -> tuple[str, int]:
                 f"target mesh has no {axis!r} axis (axes: "
                 f"{tuple(backend.shape)}); pass axis= or a mesh with the "
                 f"index's data axis")
-        return "mesh", int(backend.shape[axis])
+        auto = Mesh(backend.devices, backend.axis_names,
+                    axis_types=(AxisType.Auto,) * len(backend.axis_names))
+        return "mesh", int(backend.shape[axis]), auto
     if isinstance(backend, str) and backend == "single":
-        return "single", 1
+        return "single", 1, backend
     raise TypeError(
         f"backend must be 'single' or a jax Mesh, got {backend!r}")
+
 
 class Index:
     """Stateful SIVF session handle; see module docstring for the contract.
@@ -520,7 +540,11 @@ class Index:
     centroids:  ``[n_lists, dim]`` coarse-quantizer centroids.
     backend:    ``"single"`` (default) or a ``jax.sharding.Mesh`` whose
                 ``axis`` dimension data-shards the index (paper §4.2).
-    impl:       scan->top-k backend: "xla" | "pallas" | "pallas_interpret".
+    impl:       scan->top-k backend. ``None`` (default) picks it from the
+                platform: the fused Pallas kernel ("pallas") on a TPU, the
+                XLA reference ("xla") elsewhere. Name "xla", "pallas" or
+                "pallas_interpret" (the kernel in the Pallas interpreter,
+                for CPU tests) to force one; see :attr:`impl`.
     block_q:    fused kernel query-tile height.
     use_tables: dense-table vs pointer-walk slab lookup (None = cfg default).
     strict:     raise :class:`MutationRejected` on any per-batch error bit
@@ -540,7 +564,8 @@ class Index:
     """
 
     def __init__(self, cfg: SIVFConfig, centroids, backend="single", *,
-                 axis: str = "data", impl: str = "xla", block_q: int = 8,
+                 axis: str = "data", impl: str | None = None,
+                 block_q: int = 8,
                  use_tables: bool | None = None, strict: bool = False,
                  min_bucket: int = 64, deferred: bool = False,
                  pq_codebooks=None, telemetry=None,
@@ -567,7 +592,7 @@ class Index:
         self._use_tables = use_tables
         if pq_codebooks is not None:
             pq_codebooks = jnp.asarray(pq_codebooks, jnp.float32)
-        self._backend_kind, _ = _resolve_backend(backend, axis)
+        self._backend_kind, _, backend = _resolve_backend(backend, axis)
         if self._backend_kind == "single":
             self._mesh = None
             self._ops = _single_ops(cfg, impl, self._block_q, use_tables)
@@ -637,6 +662,12 @@ class Index:
     @property
     def n_shards(self) -> int:
         return self._ops.n_shards
+
+    @property
+    def impl(self) -> str:
+        """The scan backend searches run on (``impl`` resolved for this
+        platform by ``core.index.resolve_impl``)."""
+        return ix.resolve_impl(self._impl)
 
     @property
     def state(self) -> SlabPoolState:
@@ -805,7 +836,6 @@ class Index:
         cb = pqmod.train_pq(key, jnp.asarray(xs, jnp.float32),
                             self.cfg.pq.m, self.cfg.pq.nbits, iters=iters)
         if self._backend_kind == "mesh":
-            from jax.sharding import NamedSharding, PartitionSpec as P
             stacked = jnp.broadcast_to(cb, (self.n_shards,) + cb.shape)
             cb = jax.device_put(
                 stacked, NamedSharding(self._mesh, P(self._axis)))
@@ -1256,7 +1286,7 @@ class Index:
                     "sharded checkpoint: pass backend= — the target mesh, "
                     "or 'single' to collapse the shards onto one device")
             backend = "single"
-        tgt_kind, n_to = _resolve_backend(backend, kw["axis"])
+        tgt_kind, n_to, backend = _resolve_backend(backend, kw["axis"])
         step = mgr.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoint steps under {path}")
@@ -1308,18 +1338,20 @@ class Index:
         n_miss = {1: 3, 2: 1}.get(int(meta.get("format", 1)), 0)
         if tgt_kind == src_kind and n_to == src_shards:
             # topology match: restore leaves straight onto their devices
-            shard = None
+            shards = None
             if tgt_kind == "mesh":
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                shard = NamedSharding(backend, P(kw["axis"]))
+                shards = [dist.stack_sharding(x, backend, kw["axis"])
+                          for x in leaves]
             want = leaves[:-n_miss] if n_miss else leaves
             out = list(mgr.restore(
                 step, want,
-                sharding_tree=None if shard is None else [shard] * len(want)))
+                sharding_tree=None if shards is None
+                else shards[:len(want)]))
             if n_miss:
                 fill = [jnp.zeros(x.shape, x.dtype) for x in leaves[-n_miss:]]
-                if shard is not None:
-                    fill = [jax.device_put(f, shard) for f in fill]
+                if shards is not None:
+                    fill = [jax.device_put(f, sh) for f, sh in
+                            zip(fill, shards[-n_miss:])]
                 out += fill
             state = jax.tree.unflatten(treedef, out)
         else:
@@ -1362,7 +1394,7 @@ class Index:
         from repro.core import distributed as dist
         self.flush()
         axis = self._axis if axis is None else axis
-        tgt_kind, n_to = _resolve_backend(backend, axis)
+        tgt_kind, n_to, backend = _resolve_backend(backend, axis)
         if self._tiered is not None:
             # assemble the canonical full pool (host planes + device
             # metadata) and reshard under the untiered twin config — the

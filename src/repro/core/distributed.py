@@ -41,6 +41,8 @@ device-resident report aux that only syncs at ``Index.flush()``.
 """
 from __future__ import annotations
 
+from functools import lru_cache
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,12 +56,36 @@ from repro.core.state import (
     host_live_mask,
     init_state,
 )
-from repro.utils import shard_map_compat
 
 
 def shard_of(ids: jax.Array, n_shards: int) -> jax.Array:
     """Deterministic owner shard for each external id."""
     return jnp.where(ids >= 0, ids % n_shards, -1)
+
+
+def stack_sharding(x, mesh: Mesh, axis: str = "data") -> NamedSharding:
+    """Placement of one leaf of a stacked per-shard state: the leading axis
+    on ``axis``, except zero-size planes, which XLA returns replicated —
+    placing them so keeps jitted ops from compiling again for shardings
+    that changed between calls."""
+    return NamedSharding(mesh, P() if x.size == 0 else P(axis))
+
+
+def build_stacked(init, mesh: Mesh, axis: str, *args):
+    """``init(*args)`` repeated once per shard on a new leading axis, placed
+    with :func:`stack_sharding`. Built under ``jit`` so that each device
+    materializes only its own shard: broadcasting on one device first
+    would hold every shard's planes there at once."""
+    n = mesh.shape[axis]
+
+    def stacked(*a):
+        return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape),
+                            init(*a))
+
+    args = jax.device_put(args, NamedSharding(mesh, P()))
+    out = jax.tree.map(lambda x: stack_sharding(x, mesh, axis),
+                       jax.eval_shape(stacked, *args))
+    return jax.jit(stacked, out_shardings=out)(*args)
 
 
 def init_sharded_state(cfg: SIVFConfig, centroids: jax.Array, mesh: Mesh,
@@ -71,11 +97,8 @@ def init_sharded_state(cfg: SIVFConfig, centroids: jax.Array, mesh: Mesh,
     ``pq_codebooks`` (when ``cfg.pq`` is set) replicates to every shard,
     like the coarse centroids — shards encode and ADC-score locally.
     """
-    n = mesh.shape[axis]
-    one = init_state(cfg, centroids, pq_codebooks)
-    stacked = jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), one)
-    sharding = NamedSharding(mesh, P(axis))
-    return jax.tree.map(lambda x: jax.device_put(x, sharding), stacked)
+    return build_stacked(lambda c, cb: init_state(cfg, c, cb), mesh, axis,
+                         centroids, pq_codebooks)
 
 
 def _spec_tree(state: SlabPoolState, axis: str):
@@ -126,7 +149,7 @@ def sharded_insert(cfg: SIVFConfig, mesh: Mesh, axis: str = "data",
         out_specs = state_spec if not want_plan else (
             state_spec, {"slab": P(axis), "slot": P(axis),
                          "codes": P(axis)})
-        f = shard_map_compat(
+        f = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(state_spec, P(), P())
             + tuple(P() for _ in extra),
@@ -148,7 +171,7 @@ def sharded_delete(cfg: SIVFConfig, mesh: Mesh, axis: str = "data"):
             st = ix._delete_impl(cfg, st, i)
             return jax.tree.map(lambda x: x[None], st)
 
-        f = shard_map_compat(
+        f = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(_spec_tree(state, axis), P()),
             out_specs=_spec_tree(state, axis))
@@ -218,7 +241,7 @@ def sharded_maintain(cfg: SIVFConfig, mesh: Mesh, axis: str = "data",
         if want_plan:
             out_specs += ({"slab": P(axis), "slot": P(axis),
                            "codes": P(axis)},)
-        f = shard_map_compat(
+        f = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(state_spec, P(), P(), P(), P())
             + tuple(P() for _ in extra),
@@ -229,14 +252,15 @@ def sharded_maintain(cfg: SIVFConfig, mesh: Mesh, axis: str = "data",
 
 
 def sharded_search(cfg: SIVFConfig, mesh: Mesh, axis: str = "data",
-                   impl: str = "xla", block_q: int = 8,
+                   impl: str | None = None, block_q: int = 8,
                    use_tables: bool | None = None):
     """Scatter-gather search op: fused local top-k, all-gather, global merge.
 
     Returns ``run(state, queries, k, nprobe) -> (dists, labels)`` where
     ``k``/``nprobe`` must be trace-time constants. Each shard runs the same
     unified scan->top-k dispatch as ``core.search`` (``impl`` selects
-    xla / pallas / pallas_interpret), so only the fused [Q, k] partials ever
+    xla / pallas / pallas_interpret; None picks the platform's, see
+    ``core.index.resolve_impl``), so only the fused [Q, k] partials ever
     cross the interconnect — never per-slab candidates.
     """
 
@@ -258,7 +282,7 @@ def sharded_search(cfg: SIVFConfig, mesh: Mesh, axis: str = "data",
             return -nd, jnp.take_along_axis(lg, idx, axis=1)
 
         extra = () if fconsts is None else (fconsts,)
-        f = shard_map_compat(
+        f = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(_spec_tree(state, axis), P())
             + tuple(P() for _ in extra),
@@ -487,7 +511,7 @@ def reshard_state(cfg: SIVFConfig, state: SlabPoolState, n_from: int,
 
 
 def search_stacked(cfg: SIVFConfig, state: SlabPoolState, queries, k: int,
-                   nprobe: int, impl: str = "xla", block_q: int = 8
+                   nprobe: int, impl: str | None = None, block_q: int = 8
                    ) -> tuple[np.ndarray, np.ndarray]:
     """Search a stacked per-shard state *without* a mesh (host-side merge).
 
@@ -529,35 +553,44 @@ def place_sharded(state: SlabPoolState, mesh: Mesh, axis: str = "data"
         raise ValueError(
             f"state has {_leading_shards(state)} shards but mesh axis "
             f"{axis!r} has {n}")
-    sharding = NamedSharding(mesh, P(axis))
-    return jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), sharding),
-                        state)
+    return jax.tree.map(lambda x: jax.device_put(
+        jnp.asarray(x), stack_sharding(x, mesh, axis)), state)
 
 
 # ---------------------------------------------------------------------------
 # Legacy free-function surface (thin delegation; prefer sivf.Index)
 # ---------------------------------------------------------------------------
 
+@lru_cache(maxsize=None)
+def _jitted(builder, cfg: SIVFConfig, mesh: Mesh, axis: str, *args,
+            static_argnums=()):
+    """One jitted executable set per (op, cfg, mesh, ...): a shard-mapped
+    op called outside ``jit`` trips JAX's sharding check on zero-width
+    state planes, and a fresh ``jit`` per call would retrace every time."""
+    return jax.jit(builder(cfg, mesh, axis, *args),
+                   static_argnums=static_argnums)
+
+
 def dist_insert(cfg: SIVFConfig, mesh: Mesh, state: SlabPoolState,
                 vecs: jax.Array, ext_ids: jax.Array, axis: str = "data"
                 ) -> SlabPoolState:
     """Broadcast batch; each shard ingests the ids it owns."""
-    return sharded_insert(cfg, mesh, axis)(state, vecs, ext_ids)
+    return _jitted(sharded_insert, cfg, mesh, axis)(state, vecs, ext_ids)
 
 
 def dist_delete(cfg: SIVFConfig, mesh: Mesh, state: SlabPoolState,
                 ext_ids: jax.Array, axis: str = "data") -> SlabPoolState:
     """Broadcast deletes; non-owners see ATT misses and no-op."""
-    return sharded_delete(cfg, mesh, axis)(state, ext_ids)
+    return _jitted(sharded_delete, cfg, mesh, axis)(state, ext_ids)
 
 
 def dist_search(cfg: SIVFConfig, mesh: Mesh, state: SlabPoolState,
                 queries: jax.Array, k: int, nprobe: int, axis: str = "data",
-                impl: str = "xla", block_q: int = 8
+                impl: str | None = None, block_q: int = 8
                 ) -> tuple[jax.Array, jax.Array]:
     """Scatter-gather search across the mesh (see ``sharded_search``)."""
-    return sharded_search(cfg, mesh, axis, impl, block_q)(
-        state, queries, k, nprobe)
+    return _jitted(sharded_search, cfg, mesh, axis, impl, block_q,
+                   static_argnums=(2, 3))(state, queries, k, nprobe)
 
 
 def total_live(state: SlabPoolState) -> int:

@@ -475,7 +475,8 @@ def scan_slabs_topk(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
     Memory-bounded: scans the slab table column-by-column keeping a running
     [Q, k] result, the jnp analogue of Alg. 3's per-lane register top-k.
     The fused Pallas kernel (kernels/sivf_scan/fused.py) is the TPU
-    analogue and matches this reference bit-for-bit, ties included.
+    analogue: same selection and tie-breaking, distances within f32
+    rounding of the dot product.
     ``fstruct``/``fconsts`` (core/filters.py) AND a per-slot predicate mask
     into the validity mask *before* the fold — filtered-out candidates
     score +inf / label -1, exactly like deleted slots, so they can never
@@ -494,7 +495,10 @@ def scan_slabs_topk(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
         pm = _filter_mask(cfg, state, sc, fstruct, fconsts)
         if pm is not None:
             ok = ok & pm
-        dot = jnp.einsum("qd,qcd->qc", qf, x)
+        # elementwise f32 products reduced like the cached norms (a TPU's
+        # default f32 matmul would round operands to bf16), so a stored
+        # vector scores itself at exactly 0
+        dot = jnp.sum(qf[:, None, :] * x, axis=-1)
         if cfg.metric == "l2":
             d = qq[:, None] - 2.0 * dot + state.norms[sc]
         else:
@@ -572,19 +576,80 @@ def scan_slabs_topk_pq(cfg: SIVFConfig, state: SlabPoolState,
 
 
 SEARCH_IMPLS = ("xla", "pallas", "pallas_interpret")
+# Scalar-prefetch budget: each Pallas scan call stages its whole [Q, T]
+# int32 slab table in SMEM, which holds 1 MiB on a TPU v5e. Half of it is
+# left for Mosaic's own scalars and the filter constants.
+SMEM_TABLE_BYTES = 512 * 1024
+
+
+def resolve_impl(impl: str | None) -> str:
+    """The scan backend ``impl`` names; ``None`` picks it from the platform.
+
+    ``None`` resolves to the fused Pallas kernel ("pallas") when JAX's
+    default backend is a TPU and to the XLA column scan ("xla") anywhere
+    else. The Pallas interpreter ("pallas_interpret") runs only when a
+    caller names it.
+    """
+    if impl is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl not in SEARCH_IMPLS:
+        raise ValueError(
+            f"unknown impl {impl!r}; expected one of {SEARCH_IMPLS} or None")
+    return impl
+
+
+def smem_rows(t: int, block_q: int) -> int:
+    """Query rows per Pallas call whose ``[rows, t]`` slab table fits
+    :data:`SMEM_TABLE_BYTES` (a multiple of ``block_q`` when it can be)."""
+    rows = SMEM_TABLE_BYTES // (4 * t)
+    if rows < 1:
+        raise ValueError(
+            f"one query's slab table has {t} entries ({4 * t} B), more than "
+            f"the {SMEM_TABLE_BYTES} B SMEM budget of the Pallas scan "
+            f"kernels (SMEM_TABLE_BYTES); lower nprobe or max_chain")
+    return rows - rows % block_q if rows >= block_q else rows
+
+
+def _split_queries(kernel, per_query: tuple, table: jax.Array, rows: int
+                   ) -> tuple[jax.Array, jax.Array]:
+    """``kernel(*per_query, table)`` over query chunks of ``rows`` rows.
+
+    One ``lax.map`` step per chunk, so each Pallas call prefetches at most
+    ``rows`` table rows into SMEM; ragged tails pad with -1 table rows
+    (masked to +inf in the kernel) and are cut off again.
+    """
+    qn, _ = table.shape
+    if qn <= rows:
+        return kernel(*per_query, table)
+    n = -(-qn // rows)
+    pad = n * rows - qn
+
+    def chunks(x, fill):
+        if pad:
+            x = jnp.concatenate(
+                [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
+        return x.reshape((n, rows) + x.shape[1:])
+
+    xs = tuple(chunks(x, 0) for x in per_query) + (chunks(table, -1),)
+    d, lab = jax.lax.map(lambda a: kernel(*a), xs)
+    return d.reshape(n * rows, -1)[:qn], lab.reshape(n * rows, -1)[:qn]
 
 
 def _scan_dispatch(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
-                   table: jax.Array, k: int, impl: str, block_q: int,
+                   table: jax.Array, k: int, impl: str | None, block_q: int,
                    fstruct: tuple | None = None,
                    fconsts: jax.Array | None = None
                    ) -> tuple[jax.Array, jax.Array]:
     """Route a gathered slab table through one scan->top-k backend.
 
     Every backend streams: none materializes the [Q, T*C] candidate matrix.
-      "xla"              — jnp column scan (CPU, dry-run, shard_map bodies);
-      "pallas"           — the fused TPU kernel (kernels/sivf_scan/fused.py);
+      "xla"              — jnp column scan (the reference; CPU default);
+      "pallas"           — the fused TPU kernel (kernels/sivf_scan/fused.py;
+                           TPU default);
       "pallas_interpret" — same kernel, Pallas interpreter (CPU emulation).
+    ``impl=None`` picks the platform's backend (:func:`resolve_impl`). The
+    Pallas backends split the query batch so that each call's slab table
+    fits the SMEM budget (:func:`smem_rows`).
 
     With ``cfg.pq`` set every backend scores compressed slabs by ADC
     (``scan_slabs_topk_pq`` / kernels/sivf_scan/pq_fused.py): the uint8
@@ -596,9 +661,12 @@ def _scan_dispatch(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
     into their validity mask, the Pallas kernels read the constants from a
     second scalar-prefetch operand in SMEM and mask before the top-k fold.
     """
+    impl = resolve_impl(impl)
     if fstruct is not None and cfg.n_attrs == 0:
         raise ValueError("filtered search needs SIVFConfig(attributes=...)")
-    if cfg.pq is not None and impl in SEARCH_IMPLS:
+    interpret = impl == "pallas_interpret"
+    attrs = state.attrs if fstruct is not None else None
+    if cfg.pq is not None:
         # one ADC table build serves whichever backend scores with it
         adc = pqmod.adc_tables(state.pq_codebooks,
                                queries.astype(jnp.float32), cfg.metric)
@@ -608,32 +676,33 @@ def _scan_dispatch(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
         from repro.kernels.sivf_scan.pq_fused import (
             sivf_pq_fused_search_pallas,
         )
-        return sivf_pq_fused_search_pallas(
-            adc, table, state.codes, state.ids, state.bitmap, k,
-            block_q=block_q, interpret=impl == "pallas_interpret",
-            attrs=state.attrs if fstruct is not None else None,
-            fstruct=fstruct, fconsts=fconsts)
-    if impl == "xla":
-        return scan_slabs_topk(cfg, state, queries, table, k,
-                               fstruct=fstruct, fconsts=fconsts)
-    if impl in ("pallas", "pallas_interpret"):
-        if fstruct is not None:
-            from repro.kernels.sivf_scan.fused import sivf_fused_search_pallas
+
+        def kernel(adc, table):
+            return sivf_pq_fused_search_pallas(
+                adc, table, state.codes, state.ids, state.bitmap, k,
+                block_q=block_q, interpret=interpret, attrs=attrs,
+                fstruct=fstruct, fconsts=fconsts)
+        per_query = (adc,)
+    else:
+        if impl == "xla":
+            return scan_slabs_topk(cfg, state, queries, table, k,
+                                   fstruct=fstruct, fconsts=fconsts)
+        from repro.kernels.sivf_scan.fused import sivf_fused_search_pallas
+
+        def kernel(queries, table):
             return sivf_fused_search_pallas(
-                queries.astype(jnp.float32), table, state.data, state.ids,
-                state.norms, state.bitmap, k, metric=cfg.metric,
-                block_q=block_q, interpret=impl == "pallas_interpret",
-                attrs=state.attrs, fstruct=fstruct, fconsts=fconsts)
-        from repro.kernels.sivf_scan import ops as scan_ops
-        return scan_ops.sivf_fused_search(
-            queries.astype(jnp.float32), table, state.data, state.ids,
-            state.norms, state.bitmap, k, metric=cfg.metric,
-            block_q=block_q, interpret=impl == "pallas_interpret")
-    raise ValueError(f"unknown impl {impl!r}; expected one of {SEARCH_IMPLS}")
+                queries, table, state.data, state.ids, state.norms,
+                state.bitmap, k, metric=cfg.metric, block_q=block_q,
+                interpret=interpret, attrs=attrs, fstruct=fstruct,
+                fconsts=fconsts)
+        per_query = (queries.astype(jnp.float32),)
+    return _split_queries(kernel, per_query, table,
+                          smem_rows(table.shape[1], block_q))
 
 
 def _search_impl(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
-                 k: int, nprobe: int, use_tables: bool | None, impl: str,
+                 k: int, nprobe: int, use_tables: bool | None,
+                 impl: str | None,
                  block_q: int, fstruct: tuple | None = None,
                  fconsts: jax.Array | None = None
                  ) -> tuple[jax.Array, jax.Array]:
@@ -650,7 +719,7 @@ def _search_impl(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
                                    "impl", "block_q", "fstruct"))
 def search(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
            k: int, nprobe: int, use_tables: bool | None = None,
-           impl: str = "xla", block_q: int = 8,
+           impl: str | None = None, block_q: int = 8,
            fstruct: tuple | None = None,
            fconsts: jax.Array | None = None
            ) -> tuple[jax.Array, jax.Array]:
@@ -658,10 +727,11 @@ def search(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
 
     ``use_tables`` selects the beyond-paper dense-table slab lookup (default
     from config); both the dense-table and pointer-walk tables feed the same
-    fused scan->top-k dispatch. ``impl``: "xla" (jnp math, used for CPU +
-    dry-run), "pallas" (fused TPU kernel), or "pallas_interpret" (the fused
-    kernel under the Pallas interpreter). ``block_q`` is the fused kernel's
-    query-tile height.
+    fused scan->top-k dispatch. ``impl``: "xla" (the jnp reference),
+    "pallas" (fused TPU kernel), "pallas_interpret" (the fused kernel under
+    the Pallas interpreter), or ``None`` (the default: "pallas" on a TPU,
+    "xla" elsewhere; :func:`resolve_impl`). ``block_q`` is the fused
+    kernel's query-tile height.
 
     ``fstruct``/``fconsts`` come from ``filters.compile_filter``: the
     structure is a *static* argument (one executable per filter shape), the

@@ -11,7 +11,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.utils import l2_sq
+from repro.utils import HIGHEST, l2_sq
 
 
 @partial(jax.jit, static_argnames=("n_lists", "iters"))
@@ -25,7 +25,7 @@ def train_kmeans(key: jax.Array, xs: jax.Array, n_lists: int, iters: int = 10
     def step(cents, _):
         assign = jnp.argmin(l2_sq(xs, cents), axis=1)              # [N]
         onehot = jax.nn.one_hot(assign, n_lists, dtype=xs.dtype)   # [N, L]
-        sums = onehot.T @ xs                                        # [L, D]
+        sums = jnp.matmul(onehot.T, xs, precision=HIGHEST)          # [L, D]
         counts = jnp.sum(onehot, axis=0)[:, None]                   # [L, 1]
         new = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), cents)
         return new, None
@@ -37,7 +37,7 @@ def train_kmeans(key: jax.Array, xs: jax.Array, n_lists: int, iters: int = 10
 def assign(centroids: jax.Array, xs: jax.Array, metric: str = "l2") -> jax.Array:
     """Route vectors to their IVF list. xs [B, D] -> [B] int32."""
     if metric == "ip":
-        scores = xs @ centroids.T
+        scores = jnp.matmul(xs, centroids.T, precision=HIGHEST)
         return jnp.argmax(scores, axis=1).astype(jnp.int32)
     return jnp.argmin(l2_sq(xs, centroids), axis=1).astype(jnp.int32)
 
@@ -46,7 +46,7 @@ def probe(centroids: jax.Array, qs: jax.Array, nprobe: int, metric: str = "l2"
           ) -> jax.Array:
     """Top-nprobe coarse lists per query. qs [Q, D] -> [Q, nprobe] int32."""
     if metric == "ip":
-        scores = qs @ centroids.T
+        scores = jnp.matmul(qs, centroids.T, precision=HIGHEST)
     else:
         scores = -l2_sq(qs, centroids)
     _, lists = jax.lax.top_k(scores, nprobe)

@@ -208,7 +208,7 @@ def _plan_ops(cfg: SIVFConfig, use_tables: bool | None):
 
 
 @lru_cache(maxsize=None)
-def _scan_ops(cfg: SIVFConfig, impl: str, block_q: int):
+def _scan_ops(cfg: SIVFConfig, impl: str | None, block_q: int):
     """Stage 3: frame-translate the table and run the unmodified dispatch."""
 
     @partial(jax.jit, static_argnames=("k", "fstruct"))
@@ -343,7 +343,8 @@ class TieredRuntime:
                  "evictions")
 
     def __init__(self, cfg: SIVFConfig, backend_kind: str, mesh=None,
-                 axis: str = "data", impl: str = "xla", block_q: int = 8,
+                 axis: str = "data", impl: str | None = None,
+                 block_q: int = 8,
                  use_tables: bool | None = None, n_shards: int = 1,
                  stores: list[HostStore] | None = None, telemetry=None):
         if not cfg.tiered:
@@ -392,14 +393,11 @@ class TieredRuntime:
     # -- construction helpers ----------------------------------------------
 
     def _init_cache_dev(self) -> SlabCacheDev:
-        one = init_cache(self.cfg)
         if self.backend_kind != "mesh":
-            return one
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        stacked = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (self.n_shards,) + x.shape), one)
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        return jax.tree.map(lambda x: jax.device_put(x, sharding), stacked)
+            return init_cache(self.cfg)
+        from repro.core.distributed import build_stacked
+        return build_stacked(lambda: init_cache(self.cfg), self.mesh,
+                             self.axis)
 
     # -- insert-plan pipeline ----------------------------------------------
 
@@ -706,7 +704,6 @@ def _plan_ops_mesh(cfg: SIVFConfig, mesh, axis: str,
                    use_tables: bool | None):
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils import shard_map_compat
     ut = cfg.track_tables if use_tables is None else use_tables
 
     @partial(jax.jit, static_argnames=("nprobe",))
@@ -719,7 +716,7 @@ def _plan_ops_mesh(cfg: SIVFConfig, mesh, axis: str,
                                                                lists)
             return tab[None]
 
-        f = shard_map_compat(
+        f = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(jax.tree.map(lambda _: P(axis), state), P()),
             out_specs=P(axis))
@@ -729,11 +726,9 @@ def _plan_ops_mesh(cfg: SIVFConfig, mesh, axis: str,
 
 
 @lru_cache(maxsize=None)
-def _scan_ops_mesh(cfg: SIVFConfig, mesh, axis: str, impl: str,
+def _scan_ops_mesh(cfg: SIVFConfig, mesh, axis: str, impl: str | None,
                    block_q: int):
     from jax.sharding import PartitionSpec as P
-
-    from repro.utils import shard_map_compat
 
     @partial(jax.jit, static_argnames=("k", "fstruct"))
     def scan(state, cache, queries, table, k, fstruct, fconsts):
@@ -755,7 +750,7 @@ def _scan_ops_mesh(cfg: SIVFConfig, mesh, axis: str, impl: str,
             return -nd, jnp.take_along_axis(lg, idx, axis=1)
 
         extra = () if fconsts is None else (fconsts,)
-        f = shard_map_compat(
+        f = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(jax.tree.map(lambda _: P(axis), state),
                       jax.tree.map(lambda _: P(axis), cache), P(), P(axis))

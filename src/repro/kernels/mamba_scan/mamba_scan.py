@@ -19,14 +19,12 @@ def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_ref):
     a = a_ref[...]                                             # [bd, n]
     d = d_ref[...]                                             # [1, bd]
 
-    # bare-int indices are rejected by older pallas releases; use size-1
-    # dynamic slices and flatten instead
     def body(t, _):
-        row = (pl.dslice(0, 1), pl.dslice(t, 1), slice(None))
-        u_t = pl.load(u_ref, row).reshape(-1)                  # [bd]
-        dt_t = pl.load(dt_ref, row).reshape(-1)
-        b_t = pl.load(b_ref, row).reshape(-1)                  # [n]
-        c_t = pl.load(c_ref, row).reshape(-1)
+        row = (pl.ds(0, 1), pl.ds(t, 1), slice(None))
+        u_t = u_ref[row].reshape(-1)                           # [bd]
+        dt_t = dt_ref[row].reshape(-1)
+        b_t = b_ref[row].reshape(-1)                           # [n]
+        c_t = c_ref[row].reshape(-1)
         da = jnp.exp(dt_t.reshape(-1, 1) * a)                  # [bd, n]
         h = da * h_ref[...] + (dt_t * u_t).reshape(-1, 1) * b_t.reshape(1, -1)
         h_ref[...] = h
@@ -34,8 +32,7 @@ def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_ref):
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [bd, 1]
         y = y.reshape(1, -1) + d * u_t.reshape(1, -1)
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y.reshape(1, 1, -1))
+        y_ref[row] = y.reshape(1, 1, -1)
         return 0
 
     jax.lax.fori_loop(0, t_len, body, 0)

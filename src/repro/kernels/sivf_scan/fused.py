@@ -29,8 +29,9 @@ Peak memory is ``O(Q*k + bq*D + C*D)`` instead of the unfused
 Tie-breaking matches the XLA reference ``core.index.scan_slabs_topk``
 exactly: the running buffer occupies the low indices of the merge row and
 ``lax.top_k`` (reference) / first-index-argmin (here) both prefer lower
-indices, so distances AND labels agree bit-for-bit with the streaming
-reference on every slab order.
+indices. Both score in full f32 (the MXU at ``Precision.HIGHEST`` here,
+elementwise products there), so distances agree within f32 rounding of
+the dot product and labels agree wherever distances are not tied.
 """
 from __future__ import annotations
 
@@ -43,6 +44,37 @@ from jax.experimental.pallas import tpu as pltpu
 
 WORD_BITS = 32
 _NEG = -(2 ** 31) + 1  # python literal; jnp scalars would be captured consts
+META_ROWS = 8          # sublane tile: [n_slabs, X] metadata planes are
+#                        fetched as 8-slab blocks (a 1-row block breaks the
+#                        TPU's (8, 128) block-shape rule)
+
+
+def slab_index_maps(bq: int, t: int, n_slabs: int):
+    """``BlockSpec`` index maps driven by the scalar-prefetched slab table.
+
+    Returns ``(rows, payload_ix, meta_ix)``: ``payload_ix`` picks a slab's
+    ``[1, C, X]`` payload block; ``meta_ix`` picks the ``[rows, X]`` block
+    of a ``[n_slabs, X]`` metadata plane (ids, norms, bitmap) that holds
+    the slab, whose row inside it is ``slab % rows`` (:func:`meta_row`).
+    Empty table entries (-1) fetch slab 0 and are masked in the kernel.
+    """
+    rows = min(META_ROWS, n_slabs)
+
+    def slab(qt, qj, ti, tab):
+        return jnp.maximum(tab[(qt * bq + qj) * t + ti], 0)
+
+    def payload_ix(qt, qj, ti, tab, *_):
+        return (slab(qt, qj, ti, tab), 0, 0)
+
+    def meta_ix(qt, qj, ti, tab, *_):
+        return (slab(qt, qj, ti, tab) // rows, 0)
+
+    return rows, payload_ix, meta_ix
+
+
+def meta_row(slab, rows: int):
+    """Row of ``slab`` inside the metadata block ``slab_index_maps`` chose."""
+    return pl.ds(jnp.maximum(slab, 0) % rows, 1)
 
 
 def _unpack_bitmap(words: jax.Array, capacity: int) -> jax.Array:
@@ -66,7 +98,9 @@ def fold_topk(outd_ref, outl_ref, qj, d, lab, *, capacity: int, k: int
     reference's concatenate order, so first-index tie-breaking matches.
     Shared by the raw fused kernel (here) and the PQ ADC kernel
     (``pq_fused.py``) — candidates that score bit-identically therefore
-    select bit-identically.
+    select bit-identically. The k extracted minima build up in the loop
+    carry and the output row is stored once: Mosaic cannot store a single
+    element at a dynamic lane offset.
     """
     run_d = outd_ref[pl.ds(qj, 1), :]                   # [1, k]
     run_l = outl_ref[pl.ds(qj, 1), :]
@@ -74,8 +108,10 @@ def fold_topk(outd_ref, outl_ref, qj, d, lab, *, capacity: int, k: int
     cl = jnp.concatenate([run_l, lab], axis=1)
     m = k + capacity
     col = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-    def body(j, cur):
+    def body(j, carry):
+        cur, rd, rl = carry
         lo = jnp.min(cur, axis=1, keepdims=True)        # [1, 1]
         ix = jnp.min(jnp.where(cur == lo, col, m), axis=1, keepdims=True)
         oh = col == ix
@@ -84,11 +120,13 @@ def fold_topk(outd_ref, outl_ref, qj, d, lab, *, capacity: int, k: int
         # true min is +inf; every genuinely-inf slot carries label -1
         # (dead / pad / init), so force -1 there instead of the stale label
         lj = jnp.where(jnp.isinf(lo), -1, lj)
-        pl.store(outd_ref, (pl.dslice(qj, 1), pl.dslice(j, 1)), lo)
-        pl.store(outl_ref, (pl.dslice(qj, 1), pl.dslice(j, 1)), lj)
-        return jnp.where(oh, jnp.inf, cur)
+        rd = jnp.where(kcol == j, lo, rd)
+        rl = jnp.where(kcol == j, lj, rl)
+        return jnp.where(oh, jnp.inf, cur), rd, rl
 
-    jax.lax.fori_loop(0, k, body, cd)
+    _, rd, rl = jax.lax.fori_loop(0, k, body, (cd, run_d, run_l))
+    outd_ref[pl.ds(qj, 1), :] = rd
+    outl_ref[pl.ds(qj, 1), :] = rl
 
 
 def predicate_mask(attrs_ref, consts_ref, fstruct: tuple) -> jax.Array:
@@ -123,6 +161,7 @@ def _kernel(table_ref, *refs, capacity: int, k: int, metric: str,
     t = pl.num_programs(2)
     qi = pl.program_id(0) * bq + qj                     # global query row
     slab = table_ref[qi * t + ti]                       # scalar, may be -1
+    row = meta_row(slab, ids_ref.shape[0])
 
     # first touch of this output block: reset the running top-k
     @pl.when((qj == 0) & (ti == 0))
@@ -133,23 +172,25 @@ def _kernel(table_ref, *refs, capacity: int, k: int, metric: str,
     # -- score one (query, slab) pair on the MXU ---------------------------
     q = q_ref[pl.ds(qj, 1), :]                          # [1, D]
     x = data_ref[0]                                     # [C, D]
+    # HIGHEST: the MXU's default pass rounds f32 operands to bf16
     dot = jax.lax.dot_general(
         q.astype(jnp.float32), x.astype(jnp.float32),
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)             # [1, C]
     if metric == "l2":
         qq = jnp.sum(q.astype(jnp.float32) ** 2)
-        d = qq - 2.0 * dot + norms_ref[...]
+        d = qq - 2.0 * dot + norms_ref[row, :]
     else:
         d = -dot
 
-    valid = _unpack_bitmap(bitmap_ref[...], capacity) & (slab >= 0)
+    valid = _unpack_bitmap(bitmap_ref[row, :], capacity) & (slab >= 0)
     if fstruct is not None:
         # filtered-out slots fail exactly like deleted slots (+inf / -1):
         # they can never displace a passing candidate from the top-k
         valid &= predicate_mask(attrs_ref, consts_ref, fstruct)
     d = jnp.where(valid, d, jnp.inf)
-    lab = jnp.where(valid, ids_ref[...], -1)
+    lab = jnp.where(valid, ids_ref[row, :], -1)
 
     # -- fold candidates into the running [1, k] row -----------------------
     fold_topk(outd_ref, outl_ref, qj, d, lab, capacity=capacity, k=k)
@@ -178,7 +219,7 @@ def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
     """
     qn, d_dim = queries.shape
     t = table.shape[1]
-    _, c, _ = data.shape
+    ns, c = ids.shape
     w = bitmap.shape[1]
     filtered = fstruct is not None
 
@@ -192,12 +233,7 @@ def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
     qp = qn + pad
 
     grid = (qp // bq, bq, t)
-
-    def slab_ix(qt, qj, ti, tab, *_):
-        return (jnp.maximum(tab[(qt * bq + qj) * t + ti], 0), 0, 0)
-
-    def slab_ix2(qt, qj, ti, tab, *_):
-        return (jnp.maximum(tab[(qt * bq + qj) * t + ti], 0), 0)
+    rows, slab_ix, meta_ix = slab_index_maps(bq, t, ns)
 
     def q_ix(qt, qj, ti, *_):
         return (qt, 0)
@@ -205,15 +241,15 @@ def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
     in_specs = [
         pl.BlockSpec((bq, d_dim), q_ix),                             # q
         pl.BlockSpec((1, c, d_dim), slab_ix),                        # data
-        pl.BlockSpec((1, c), slab_ix2),                              # ids
-        pl.BlockSpec((1, c), slab_ix2),                              # norms
+        pl.BlockSpec((rows, c), meta_ix),                            # ids
+        pl.BlockSpec((rows, c), meta_ix),                            # norms
     ]
     operands = [queries, data, ids, norms]
     if filtered:
         a = attrs.shape[-1]
         in_specs.append(pl.BlockSpec((1, a, c), slab_ix))            # attrs
         operands.append(attrs.swapaxes(1, 2))         # [n_slabs, A, C]
-    in_specs.append(pl.BlockSpec((1, w), slab_ix2))                  # bitmap
+    in_specs.append(pl.BlockSpec((rows, w), meta_ix))                # bitmap
     operands.append(bitmap)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -237,7 +273,7 @@ def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
             jax.ShapeDtypeStruct((qp, k), jnp.float32),
             jax.ShapeDtypeStruct((qp, k), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*prefetch, *operands)

@@ -40,7 +40,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.sivf_scan.fused import (
     _unpack_bitmap,
     fold_topk,
+    meta_row,
     predicate_mask,
+    slab_index_maps,
 )
 
 
@@ -59,6 +61,7 @@ def _pq_kernel(table_ref, *refs, capacity: int, k: int, m: int,
     t = pl.num_programs(2)
     qi = pl.program_id(0) * bq + qj                     # global query row
     slab = table_ref[qi * t + ti]                       # scalar, may be -1
+    row = meta_row(slab, ids_ref.shape[0])
 
     @pl.when((qj == 0) & (ti == 0))
     def _init():
@@ -81,12 +84,12 @@ def _pq_kernel(table_ref, *refs, capacity: int, k: int, m: int,
             preferred_element_type=jnp.float32)         # [1, C]
         d = term if d is None else d + term
 
-    valid = _unpack_bitmap(bitmap_ref[...], capacity) & (slab >= 0)
+    valid = _unpack_bitmap(bitmap_ref[row, :], capacity) & (slab >= 0)
     if fstruct is not None:
         # filtered-out slots fail exactly like deleted slots (+inf / -1)
         valid &= predicate_mask(attrs_ref, consts_ref, fstruct)
     d = jnp.where(valid, d, jnp.inf)
-    lab = jnp.where(valid, ids_ref[...], -1)
+    lab = jnp.where(valid, ids_ref[row, :], -1)
 
     fold_topk(outd_ref, outl_ref, qj, d, lab, capacity=capacity, k=k)
 
@@ -112,7 +115,7 @@ def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
     """
     qn, m, ksub = adc.shape
     t = table.shape[1]
-    _, c, _ = codes.shape
+    ns, c = ids.shape
     w = bitmap.shape[1]
     filtered = fstruct is not None
     adc = adc.reshape(qn, m * ksub)                     # row-major [s, j]
@@ -127,12 +130,7 @@ def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
     qp = qn + pad
 
     grid = (qp // bq, bq, t)
-
-    def slab_ix(qt, qj, ti, tab, *_):
-        return (jnp.maximum(tab[(qt * bq + qj) * t + ti], 0), 0, 0)
-
-    def slab_ix2(qt, qj, ti, tab, *_):
-        return (jnp.maximum(tab[(qt * bq + qj) * t + ti], 0), 0)
+    rows, slab_ix, meta_ix = slab_index_maps(bq, t, ns)
 
     def q_ix(qt, qj, ti, *_):
         return (qt, 0)
@@ -140,14 +138,14 @@ def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
     in_specs = [
         pl.BlockSpec((bq, m * ksub), q_ix),
         pl.BlockSpec((1, c, m), slab_ix),                        # codes
-        pl.BlockSpec((1, c), slab_ix2),                          # ids
+        pl.BlockSpec((rows, c), meta_ix),                        # ids
     ]
     operands = [adc, codes, ids]
     if filtered:
         a = attrs.shape[-1]
         in_specs.append(pl.BlockSpec((1, a, c), slab_ix))        # attrs
         operands.append(attrs.swapaxes(1, 2))     # [n_slabs, A, C]
-    in_specs.append(pl.BlockSpec((1, w), slab_ix2))              # bitmap
+    in_specs.append(pl.BlockSpec((rows, w), meta_ix))            # bitmap
     operands.append(bitmap)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -171,7 +169,7 @@ def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
             jax.ShapeDtypeStruct((qp, k), jnp.float32),
             jax.ShapeDtypeStruct((qp, k), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*prefetch, *operands)

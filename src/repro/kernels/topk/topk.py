@@ -28,8 +28,8 @@ def _kernel(dist_ref, lab_ref, outd_ref, outl_ref, *, k: int):
         ix = jnp.min(jnp.where(cur == m, col, nl), axis=1, keepdims=True)
         oh = col == ix                                           # [bq, L]
         lab = jnp.max(jnp.where(oh, lab_ref[...], _NEG), axis=1)
-        pl.store(outd_ref, (slice(None), pl.dslice(j, 1)), m)
-        pl.store(outl_ref, (slice(None), pl.dslice(j, 1)), lab[:, None])
+        outd_ref[:, pl.ds(j, 1)] = m
+        outl_ref[:, pl.ds(j, 1)] = lab[:, None]
         return jnp.where(oh, jnp.inf, cur)
 
     jax.lax.fori_loop(0, k, body, dist_ref[...])

@@ -18,23 +18,18 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref):
     s_ref[...] = jnp.zeros_like(s_ref)
     u = u_ref[...]                                           # [1, dk]
 
-    # bare-int indices are rejected by older pallas releases; use size-1
-    # dynamic slices and flatten instead
     def body(t, _):
-        row = (pl.dslice(0, 1), pl.dslice(t, 1), pl.dslice(0, 1), slice(None))
-        r_t = pl.load(r_ref, row).reshape(1, -1)
-        k_t = pl.load(k_ref, row).reshape(1, -1)
-        v_t = pl.load(v_ref, row).reshape(1, -1)
-        w_t = pl.load(w_ref, row).reshape(1, -1)
+        row = (pl.ds(0, 1), pl.ds(t, 1), pl.ds(0, 1), slice(None))
+        r_t = r_ref[row].reshape(1, -1)
+        k_t = k_ref[row].reshape(1, -1)
+        v_t = v_ref[row].reshape(1, -1)
+        w_t = w_ref[row].reshape(1, -1)
         kv = k_t.reshape(-1, 1) * v_t                        # [dk, dv]
         s = s_ref[...]
         y = jax.lax.dot_general(                              # [1, dv]
             r_t, s + u.reshape(-1, 1) * kv,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        pl.store(y_ref,
-                 (pl.dslice(0, 1), pl.dslice(t, 1), pl.dslice(0, 1),
-                  slice(None)),
-                 y.reshape(1, 1, 1, -1))
+        y_ref[row] = y.reshape(1, 1, 1, -1)
         s_ref[...] = w_t.reshape(-1, 1) * s + kv
         return 0
 

@@ -35,7 +35,6 @@ from repro.sharding import axes as AX
 from repro.sharding.rules import make_plan
 from repro.train.train_step import (TrainConfig, init_train_state,
                                     make_train_step, state_specs)
-from repro.utils import set_mesh_compat
 
 
 def _to_dtype(tree, dtype):
@@ -64,7 +63,7 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool) -> dict:
     batch_sh = SP.input_shardings(cfg, shape, plan, mesh)
 
     t0 = time.time()
-    with set_mesh_compat(mesh), AX.use_rules(rules):
+    with jax.set_mesh(mesh), AX.use_rules(rules):
         if shape.kind == "train":
             tcfg = TrainConfig()
             step_fn = make_train_step(cfg, plan, tcfg)

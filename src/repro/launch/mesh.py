@@ -22,7 +22,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         d, m = 16, 16
     shape = (2, d, m) if multi_pod else (d, m)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the LM stack places activations with sharding constraints,
+    # which jax.make_mesh's default Explicit axes turn into assertions
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_axes_dict(mesh) -> dict[str, int]:

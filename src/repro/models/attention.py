@@ -18,7 +18,6 @@ from repro.configs.base import ModelConfig
 from repro.models.common import apply_rope, dense, dense_init, rms_norm_1d
 from repro.sharding.axes import annot, constrain
 from repro.sharding.rules import ShardPlan
-from repro.utils import shard_map_compat
 
 
 def _head_mask(plan: ShardPlan, n_real: int) -> jax.Array:
@@ -332,7 +331,7 @@ def decode_attn_stacked(p_attn, cfg, plan: ShardPlan, x, sk, sv, layer_i,
         q_spec = spec_for(("batch", None, None, None), rules)
         c_spec = spec_for((None, "batch", "kv_seq", head_ax, None), rules)
         u_spec = spec_for((None, "batch", None, None, None), rules)
-        sk, sv, o = shard_map_compat(
+        sk, sv, o = jax.shard_map(
             local, mesh=mesh, check_vma=False,
             in_specs=(q_spec, c_spec, c_spec, u_spec, u_spec, P(), P()),
             out_specs=(c_spec, c_spec, q_spec),
@@ -408,7 +407,7 @@ def _decode_attn_seqshard(plan: ShardPlan, q, cache_k, cache_v, k_new,
     q_spec = spec_for(("batch", None, None, None), rules)
     c_spec = spec_for(("batch", "kv_seq",
                        "kv_heads" if plan.kv_sharded else None, None), rules)
-    ck, cv, o = shard_map_compat(
+    ck, cv, o = jax.shard_map(
         local, mesh=mesh, check_vma=False,
         in_specs=(q_spec, c_spec, c_spec, q_spec, q_spec, P()),
         out_specs=(c_spec, c_spec, q_spec),

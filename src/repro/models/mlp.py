@@ -17,7 +17,7 @@ from repro.configs.base import ModelConfig
 from repro.models.common import dense, dense_init
 from repro.sharding.axes import constrain
 from repro.sharding.rules import ShardPlan
-from repro.utils import round_up, shard_map_compat
+from repro.utils import round_up
 
 
 # -- dense MLP ---------------------------------------------------------------
@@ -148,7 +148,7 @@ def apply_moe_shardmap(p, cfg: ModelConfig, plan: ShardPlan, x):
     x_spec = spec_for(("batch", "seq_sp", None), rules)
     router_spec = P()   # router weight replicated inside the region
     w_spec = spec_for(("expert", None, None), rules)
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         local, mesh=mesh, check_vma=False,
         in_specs=(x_spec, router_spec, w_spec, w_spec, w_spec),
         out_specs=(x_spec, P(plan.batch_axes + ("model",))),

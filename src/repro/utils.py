@@ -5,28 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=)``
-    (same flag, earlier name).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
-def set_mesh_compat(mesh):
-    """Context manager entering ``mesh``: ``jax.set_mesh`` on new jax,
-    the Mesh's own context-manager protocol on older releases."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+# full-f32 matmuls on a TPU (its default precision rounds operands to bf16)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def ceil_div(a, b):
@@ -62,9 +42,11 @@ def l2_sq(a: jax.Array, b: jax.Array) -> jax.Array:
     """Pairwise squared L2 distance, ``a [N,D]`` x ``b [M,D]`` -> ``[N,M]``.
 
     Uses the ||a||^2 - 2 a.b + ||b||^2 expansion so the inner term hits the
-    MXU as a single matmul.
+    MXU as a single matmul, at ``HIGHEST`` precision: a TPU's default f32
+    matmul rounds operands to bf16, whose error is a fixed bias per row of
+    ``b`` large enough to move which centroid is nearest.
     """
     aa = jnp.sum(a * a, axis=-1, keepdims=True)       # [N,1]
     bb = jnp.sum(b * b, axis=-1, keepdims=True).T     # [1,M]
-    ab = a @ b.T                                       # [N,M]
+    ab = jnp.matmul(a, b.T, precision=HIGHEST)         # [N,M]
     return aa - 2.0 * ab + bb

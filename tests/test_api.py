@@ -9,8 +9,10 @@ The ISSUE-2 acceptance criteria live here:
     via the handle's measured jit-cache counters, not assumed.
 """
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,8 @@ import pytest
 import sivf
 from repro import core
 from repro.core import distributed as dist
+
+REPO = Path(__file__).resolve().parent.parent
 
 D, NL = 16, 8
 
@@ -483,9 +487,10 @@ def _run(script, *args):
     return subprocess.run(
         [sys.executable, "-c", script, *args], capture_output=True,
         text=True, timeout=560,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=REPO)
 
 
 def test_sharded_index_handle_churn():

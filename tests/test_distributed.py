@@ -1,8 +1,12 @@
 """Distributed behaviour on fake devices (subprocess: device count must be
 set before jax initializes, so these run isolated)."""
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent.parent
 
 _DIST_SCRIPT = r"""
 import os
@@ -59,11 +63,11 @@ def _run(script, *args):
         [sys.executable, "-c", script, *args], capture_output=True,
         text=True, timeout=560,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
+             "HOME": os.environ.get("HOME", ""),
              # fake-device CPU tests; avoid the TPU-probe stall on hosts
              # with libtpu installed (see conftest.py)
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=REPO)
 
 
 def test_sharded_sivf_scatter_gather():

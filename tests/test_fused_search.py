@@ -135,3 +135,40 @@ def test_search_impl_rejects_unknown(rng):
     qs = jnp.asarray(rng.normal(size=(2, D)).astype(np.float32))
     with pytest.raises(ValueError, match="unknown impl"):
         core.search(cfg, state, qs, 3, 1, impl="cuda")
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+def test_pallas_splits_query_batch_for_smem(rng, monkeypatch, metric):
+    """A batch whose slab table exceeds the SMEM budget runs as several
+    kernel calls (``lax.map`` over query chunks) and still equals the XLA
+    reference, ragged tail included."""
+    cfg, state = make(rng, metric=metric)
+    state = load(cfg, state, rng, 180)
+    t = NL * cfg.max_chain
+    # 3 rows per call: 11 queries -> chunks of 3, 3, 3, 2 (+1 pad row)
+    monkeypatch.setattr(core.index, "SMEM_TABLE_BYTES", 4 * t * 3)
+    assert core.index.smem_rows(t, block_q=8) == 3
+    parity.assert_search_parity(cfg, state, rng, k=5, nprobe=NL, q=11)
+
+
+def test_smem_rows_names_the_limit():
+    """Rows are a block_q multiple when they can be; a single row over the
+    budget raises an error that names the limit."""
+    budget = core.index.SMEM_TABLE_BYTES
+    assert core.index.smem_rows(1024, 8) == budget // 4096
+    assert core.index.smem_rows(1024, 48) == budget // 4096 // 48 * 48
+    assert core.index.smem_rows(budget // 4, 8) == 1
+    with pytest.raises(ValueError, match="SMEM_TABLE_BYTES"):
+        core.index.smem_rows(budget // 4 + 1, 8)
+
+
+def test_impl_none_resolves_from_platform():
+    """impl=None picks the kernel only on a TPU; the interpreter never
+    runs unless named."""
+    import jax
+    want = "pallas" if jax.default_backend() == "tpu" else "xla"
+    assert core.index.resolve_impl(None) == want
+    for impl in core.index.SEARCH_IMPLS:
+        assert core.index.resolve_impl(impl) == impl
+    with pytest.raises(ValueError, match="unknown impl"):
+        core.index.resolve_impl("cuda")

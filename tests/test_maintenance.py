@@ -20,8 +20,10 @@ Layers under test, matching the subsystem's structure:
     mesh-vs-single report/search parity.
 """
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -32,6 +34,8 @@ import sivf
 from repro import core
 from repro.core import maintenance as mt
 from repro.core import quantizer
+
+REPO = Path(__file__).resolve().parent.parent
 
 D, NL = 16, 4
 
@@ -300,9 +304,10 @@ def test_aborted_op_atomic_two_shard_mesh():
     r = subprocess.run(
         [sys.executable, "-c", _MESH2_SCRIPT], capture_output=True,
         text=True, timeout=560,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=REPO)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["aborted"] and out["errors"] & mt.ABORT_BITS

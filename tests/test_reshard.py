@@ -18,8 +18,10 @@ Everything asserts exact equality (``==``), not allclose: resharding
 re-routes stored bytes, it never recomputes distances differently.
 """
 import json
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +31,8 @@ import pytest
 import sivf
 from repro import core
 from repro.core import distributed as dist
+
+REPO = Path(__file__).resolve().parent.parent
 
 D, NL = 16, 8
 
@@ -333,9 +337,10 @@ def _run(script):
     return subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=560,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=REPO)
 
 
 def test_mesh_checkpoint_loads_onto_any_backend():

@@ -10,9 +10,11 @@ prefetch dedupes slab ids shared by probed lists.
 """
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import unittest.mock as mock
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,8 @@ from repro.core import filters as flt
 from repro.core.api import Index
 from repro.core.pq import PQConfig
 from repro.core.state import SIVFConfig
+
+REPO = Path(__file__).resolve().parent.parent
 
 D, NL = 16, 8
 
@@ -390,9 +394,10 @@ def test_tiered_four_shard_parity():
     r = subprocess.run(
         [sys.executable, "-c", _MESH_SCRIPT], capture_output=True,
         text=True, timeout=560,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=REPO)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["ok"]
