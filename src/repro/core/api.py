@@ -247,6 +247,11 @@ class SearchResult:
     k: int
     nprobe: int
     padded_to: int          # query bucket the batch was padded to
+    # [padded_to] int32 non-empty slab-table entries per padded row, on the
+    # device and unsliced (None where the path reports no counter: tiered,
+    # mesh); grid_steps: the scan grid's steps for the launch (0 then)
+    live_entries: jax.Array | None = None
+    grid_steps: int = 0
 
     def __iter__(self) -> Iterator:
         return iter((self.distances, self.labels))
@@ -492,8 +497,9 @@ def _mesh_ops(cfg: SIVFConfig, mesh: Mesh, axis: str, impl: str,
 
     @partial(jax.jit, static_argnums=(2, 3, 4))
     def search_fn(state, queries, k, nprobe, fstruct, fconsts):
-        return raw_search(state, queries, k, nprobe, fstruct=fstruct,
-                          fconsts=fconsts)
+        d, lab = raw_search(state, queries, k, nprobe, fstruct=fstruct,
+                            fconsts=fconsts)
+        return d, lab, None                 # no live-entry counter
 
     return SimpleNamespace(insert=insert_fn, delete=delete_fn,
                            search=search_fn, n_shards=n)
@@ -1163,12 +1169,16 @@ class Index:
                 d, lab = self._tiered.search(
                     self._state, padded, int(k), nprobe, fstruct, fconsts,
                     epoch=self._epoch, ticket=_prefetched)
+                live = None
             else:
-                d, lab = self._ops.search(self._state, padded, int(k),
-                                          nprobe, fstruct, fconsts)
+                d, lab, live = self._ops.search(self._state, padded, int(k),
+                                                nprobe, fstruct, fconsts)
         self._note_compiles()
+        steps = 0 if live is None else ix.scan_grid_steps(
+            bucket, nprobe * self.cfg.max_chain, self._block_q)
         return SearchResult(distances=d[:q], labels=lab[:q], k=int(k),
-                            nprobe=nprobe, padded_to=bucket)
+                            nprobe=nprobe, padded_to=bucket,
+                            live_entries=live, grid_steps=steps)
 
     def prefetch(self, queries, nprobe: int | None = None):
         """Stage the slabs a coming query batch will probe (tiered only).

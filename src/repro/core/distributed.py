@@ -269,9 +269,9 @@ def sharded_search(cfg: SIVFConfig, mesh: Mesh, axis: str = "data",
             ) -> tuple[jax.Array, jax.Array]:
         def local(st, q, *fc):
             st = jax.tree.map(lambda x: x[0], st)
-            d, lab = ix._search_impl(cfg, st, q, k, nprobe, use_tables, impl,
-                                     block_q, fstruct=fstruct,
-                                     fconsts=fc[0] if fc else None)
+            d, lab, _ = ix._search_impl(cfg, st, q, k, nprobe, use_tables,
+                                        impl, block_q, fstruct=fstruct,
+                                        fconsts=fc[0] if fc else None)
             # gather fused [Q, k] partials from all shards (paper MPI_Gather)
             dg = jax.lax.all_gather(d, axis)                   # [S, Q, k]
             lg = jax.lax.all_gather(lab, axis)

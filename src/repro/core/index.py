@@ -610,6 +610,20 @@ def smem_rows(t: int, block_q: int) -> int:
     return rows - rows % block_q if rows >= block_q else rows
 
 
+def scan_grid_steps(q: int, t: int, block_q: int) -> int:
+    """Grid steps the fused scan launches for ``q`` query rows over a
+    ``[q, t]`` slab table: one per (query row, table entry) of each SMEM
+    chunk (:func:`_split_queries`), the chunk padded to a ``block_q``
+    multiple as :func:`~repro.kernels.sivf_scan.fused.sivf_fused_search_pallas`
+    pads it (both scan kernels launch the same grid). Empty (-1) entries and
+    padded rows cost a step like any other. A table row past the SMEM budget
+    (only the XLA scan runs one) counts as one chunk."""
+    rows = smem_rows(t, block_q) if 4 * t <= SMEM_TABLE_BYTES else q
+    n, rows = (1, q) if q <= rows else (-(-q // rows), rows)
+    bq = max(1, min(block_q, rows))
+    return n * (-(-rows // bq) * bq) * t
+
+
 def _split_queries(kernel, per_query: tuple, table: jax.Array, rows: int
                    ) -> tuple[jax.Array, jax.Array]:
     """``kernel(*per_query, table)`` over query chunks of ``rows`` rows.
@@ -705,14 +719,21 @@ def _search_impl(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
                  impl: str | None,
                  block_q: int, fstruct: tuple | None = None,
                  fconsts: jax.Array | None = None
-                 ) -> tuple[jax.Array, jax.Array]:
-    """Un-jitted search body, shared by `search` and distributed shards."""
+                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Un-jitted search body, shared by `search` and distributed shards.
+
+    Returns ``(distances, labels, live_entries)``: ``live_entries`` [Q]
+    int32 counts each query row's non-empty (``>= 0``) slab-table entries,
+    the grid steps of the scan that do real work (:func:`scan_grid_steps`
+    counts all of them)."""
     ut = cfg.track_tables if use_tables is None else use_tables
     lists = quantizer.probe(state.centroids, queries.astype(cfg.dtype),
                             nprobe, cfg.metric)
     table = (gather_tables if ut else walk_chains)(cfg, state, lists)
-    return _scan_dispatch(cfg, state, queries, table, k, impl, block_q,
-                          fstruct=fstruct, fconsts=fconsts)
+    live = jnp.sum(table >= 0, axis=1, dtype=jnp.int32)
+    d, lab = _scan_dispatch(cfg, state, queries, table, k, impl, block_q,
+                            fstruct=fstruct, fconsts=fconsts)
+    return d, lab, live
 
 
 @partial(jax.jit, static_argnames=("cfg", "k", "nprobe", "use_tables",
@@ -738,8 +759,9 @@ def search(cfg: SIVFConfig, state: SlabPoolState, queries: jax.Array,
     constants are traced (changing ``Eq("tenant", 3)`` to ``..., 7`` hits
     the same executable).
     """
-    return _search_impl(cfg, state, queries, k, nprobe, use_tables, impl,
-                        block_q, fstruct=fstruct, fconsts=fconsts)
+    d, lab, _ = _search_impl(cfg, state, queries, k, nprobe, use_tables,
+                             impl, block_q, fstruct=fstruct, fconsts=fconsts)
+    return d, lab
 
 
 # ---------------------------------------------------------------------------

@@ -14,9 +14,20 @@ needs: a :class:`~repro.obs.metrics.MetricsRegistry`, the span tracer,
 and the rolling slow-query log (top-N root spans over a configurable
 threshold, with stage breakdown and tenant/filter/epoch provenance).
 It is **always-on-cheap**: with ``enabled=False`` (the process default)
-``span()`` returns a shared no-op context manager and every recording
-method returns after a single attribute check — instrumented code paths
-never pay for telemetry they did not ask for. The serve-churn overhead
+and no profiler recording, ``span()`` returns a shared no-op context
+manager and every recording method returns after a single attribute
+check — instrumented code paths never pay for telemetry they did not ask
+for.
+
+Spans have two sinks. The registry sink (stage histograms, slow-query
+log, Prometheus) records behind ``enabled``. The profiler sink writes
+every lexically scoped span as a ``jax.profiler.TraceAnnotation`` named
+``sivf.<name>`` whenever a ``jax.profiler`` session is recording, whether
+or not ``enabled`` is set; the span's attributes become the event's args
+and :meth:`Span.set` adds those known only at its end. The program's spans
+then share the device trace's clock. ``open_span`` / ``finish_span`` spans
+(a serve tile, whose life overlaps its neighbours on the serve thread) stay
+registry-only. The serve-churn overhead
 benchmark (``benchmarks/obs_bench.py``) gates the *enabled* cost too:
 p99 with telemetry on must stay within 5% of off.
 
@@ -26,20 +37,35 @@ Usage::
     with tel.span("serve.search", root=True, tenant="app", epoch=3):
         with tel.span("plan"):
             ...
-        with tel.span("scan"):
+        with tel.span("scan") as sp:
             ...
+            sp.set(rows=12)       # known at the end: an arg of the event
     tel.snapshot()            # JSON-able dict (metrics + slow queries)
     tel.render_prometheus()   # Prometheus text exposition
 """
 from __future__ import annotations
 
-import functools
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import MetricsRegistry
 
 STAGE_HISTOGRAM = "sivf_stage_seconds"
+PROFILER_PREFIX = "sivf."
+
+# True while a jax.profiler session records: the profiler's own switch
+# decides whether spans reach the trace
+profiling = TraceAnnotation.is_enabled
+
+
+def _event_args(attrs: dict) -> dict:
+    return {k: v for k, v in attrs.items() if v is not None}
+
+
+def _annotation(name: str, attrs: dict) -> TraceAnnotation:
+    return TraceAnnotation(PROFILER_PREFIX + name, **_event_args(attrs))
 
 
 class Span:
@@ -47,7 +73,8 @@ class Span:
     :meth:`Telemetry.open_span`. ``stages`` accumulates nested spans'
     durations (root spans only, by stage name)."""
 
-    __slots__ = ("name", "root", "attrs", "t0", "t1", "stages", "_tel")
+    __slots__ = ("name", "root", "attrs", "t0", "t1", "stages", "_tel",
+                 "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, root: bool,
                  attrs: dict, t0: float):
@@ -58,6 +85,14 @@ class Span:
         self.t0 = t0
         self.t1: float | None = None
         self.stages: dict[str, float] = {}
+        self._ann: TraceAnnotation | None = None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only at the span's end: kept on the span and,
+        while the profiler records, written to its trace event."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_event_args(attrs))
 
     @property
     def duration_s(self) -> float:
@@ -87,8 +122,34 @@ class _NoopSpan:
     def add_stage(self, stage, seconds):
         pass
 
+    def set(self, **attrs):
+        pass
+
 
 _NOOP = _NoopSpan()
+
+
+class _ProfilerSpan:
+    """A span written to the profiler trace alone (telemetry disabled)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, attrs: dict):
+        self._ann = _annotation(name, attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def add_stage(self, stage, seconds):
+        pass
+
+    def set(self, **attrs):
+        self._ann.set_metadata(**_event_args(attrs))
 
 
 class _SpanCtx:
@@ -102,9 +163,13 @@ class _SpanCtx:
 
     def __enter__(self) -> Span:
         self._tel._push(self._span)
+        if self._span._ann is not None:
+            self._span._ann.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> bool:
+        if self._span._ann is not None:
+            self._span._ann.__exit__(*exc)
         self._tel._pop(self._span)
         self._tel.finish_span(self._span)
         return False
@@ -115,8 +180,9 @@ class Telemetry:
 
     Parameters
     ----------
-    enabled:          master switch. Disabled, every entry point is a
-                      single-attribute-check no-op; flip
+    enabled:          master switch of the registry sink. Disabled, every
+                      entry point is a single-attribute-check no-op
+                      (spans still reach a recording profiler); flip
                       :attr:`enabled` at runtime to start/stop recording
                       (the overhead benchmark toggles it mid-run).
     slow_threshold_s: root spans at least this long enter the slow-query
@@ -165,13 +231,16 @@ class Telemetry:
         slow-query-log candidates. ``root="auto"`` makes the span a root
         only when no root is already open on this thread (a directly-used
         Index.search is a root; the same call under a serve tile is a
-        stage). No-op when disabled."""
+        stage). Written to the profiler trace while one records; the
+        shared no-op when disabled and no profiler records."""
         if not self.enabled:
-            return _NOOP
+            return _ProfilerSpan(name, attrs) if profiling() else _NOOP
         if root == "auto":
             root = self._enclosing_root() is None
-        return _SpanCtx(self, Span(self, name, bool(root), attrs,
-                                   self._clock()))
+        sp = Span(self, name, bool(root), attrs, self._clock())
+        if profiling():
+            sp._ann = _annotation(name, attrs)
+        return _SpanCtx(self, sp)
 
     def open_span(self, name: str, root: bool = True, **attrs
                   ) -> "Span | None":
@@ -223,16 +292,6 @@ class Telemetry:
             root = self._enclosing_root()
             if root is not None:
                 root.add_stage(stage, seconds)
-
-    def traced(self, name: str, root: bool = False):
-        """Decorator form of :meth:`span`."""
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                with self.span(name, root=root):
-                    return fn(*a, **kw)
-            return wrapper
-        return deco
 
     # -- slow-query log ------------------------------------------------------
 

@@ -122,7 +122,8 @@ class ServeSearchResult:
     coalesced: int             # live queries in the shared tile
     padded_to: int             # pow2 block_q bucket the tile padded to
     queue_s: float             # submit -> dispatch
-    service_s: float           # dispatch -> device completion
+    service_s: float           # dispatch -> results on the host (device
+                               # time plus the device -> host copy)
 
     def __iter__(self):
         return iter((self.distances, self.labels))

@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.core import filters as flt
 from repro.core.api import Index
+from repro.obs.trace import profiling
 from repro.serve.quota import (
     Backpressure,
     BackpressureKind,
@@ -173,6 +174,7 @@ class ServeEngine:
         self._n_mutations = 0
         self._n_maintenance = 0
         self._coalesce_sizes: list[int] = []
+        self._flush_deferred = 0              # cycles a flush was held back
         # telemetry: default to the index's instance so one registry holds
         # the whole request path (tile roots + plan/prefetch/scan stages)
         self._tel = telemetry if telemetry is not None \
@@ -390,15 +392,19 @@ class ServeEngine:
                             self._queue or self._closing
                             or self._mut_inflight):
                         break
-                    self._cv.wait(timeout=0.1)
+                    with self._tel.span("serve.wait"):
+                        self._cv.wait(timeout=0.1)
                 batch = list(self._queue)
                 self._queue.clear()
             searches = [r for r in batch if isinstance(r, SearchRequest)]
             muts = [r for r in batch if isinstance(r, MutationRequest)]
             maint = [r for r in batch if isinstance(r, MaintenanceRequest)]
-            dispatched = self._dispatch_searches(searches)
-            self._dispatch_mutations(muts)
-            self._dispatch_maintenance(maint)
+            with self._tel.span("serve.dispatch") as sp:
+                dispatched = self._dispatch_searches(searches)
+                self._dispatch_mutations(muts)
+                self._dispatch_maintenance(maint)
+                sp.set(tiles=len(dispatched), rows=sum(
+                    r.queries.shape[0] for d in dispatched for r in d[0]))
             self._maybe_flush()
             self._resolve_searches(dispatched)
 
@@ -529,57 +535,85 @@ class ServeEngine:
         a drain is in progress — one packed sync resolves every batch."""
         if not self._mut_inflight:
             return
-        if self._index.pending_count < self._flush_every \
-                and not self._closing:
+        if self._index.pending_count >= self._flush_every:
+            reason = "depth"
+        elif self._closing:
+            reason = "closing"
+        else:
             with self._cv:
                 if self._queue:        # more work queued: keep deferring
+                    self._flush_deferred += 1
                     return
-        try:
-            self._index.flush()
-        except Exception as e:
-            while self._mut_inflight:
-                req, _, _ = self._mut_inflight.popleft()
-                req.future.set_exception(e)
-            return
-        now = self._clock()
-        if self._tel.enabled:
-            self._m_epoch.set(self._index.epoch)
-        while self._mut_inflight:
-            req, pending, epoch = self._mut_inflight.popleft()
+            reason = "idle"
+        with self._tel.span("serve.flush", batches=len(self._mut_inflight),
+                            reason=reason,
+                            deferred=self._flush_deferred) as sp:
+            self._flush_deferred = 0
+            try:
+                self._index.flush()
+            except Exception as e:
+                while self._mut_inflight:
+                    req, _, _ = self._mut_inflight.popleft()
+                    req.future.set_exception(e)
+                return
+            now = self._clock()
             if self._tel.enabled:
-                self._tel.record_duration(
-                    "serve.mutation_queue", now - req.t_submit,
-                    attach=False)
-            req.future.set_result(ServeMutationResult(
-                report=pending.result(), epoch=epoch,
-                queue_s=now - req.t_submit))
+                self._m_epoch.set(self._index.epoch)
+            wait_s = 0.0
+            while self._mut_inflight:
+                req, pending, epoch = self._mut_inflight.popleft()
+                wait_s += now - req.t_submit
+                if self._tel.enabled:
+                    self._tel.record_duration(
+                        "serve.mutation_queue", now - req.t_submit,
+                        attach=False)
+                req.future.set_result(ServeMutationResult(
+                    report=pending.result(), epoch=epoch,
+                    queue_s=now - req.t_submit))
+            sp.set(wait_ms=wait_s * 1e3)
 
     def _resolve_searches(self, dispatched: list) -> None:
         for chunk, res, epoch, t0, span in dispatched:
-            try:
-                jax.block_until_ready(res.distances)
-                d = np.asarray(res.distances)
-                labels = np.asarray(res.labels)
-            except Exception as e:
-                self._tel.finish_span(span)
-                for r in chunk:
-                    r.future.set_exception(e)
-                continue
-            t1 = self._clock()
-            self._tel.finish_span(span)  # tile wall time ~= service_s
             total = sum(r.queries.shape[0] for r in chunk)
-            off = 0
-            for r in chunk:
-                nq = r.queries.shape[0]
-                if self._tel.enabled:
-                    self._tel.record_duration(
-                        "serve.queue", t0 - r.t_submit, attach=False)
-                r.future.set_result(ServeSearchResult(
-                    distances=d[off:off + nq], labels=labels[off:off + nq],
-                    k=res.k, nprobe=res.nprobe, epoch=epoch,
-                    coalesced=total, padded_to=res.padded_to,
-                    queue_s=t0 - r.t_submit, service_s=t1 - t0))
-                off += nq
+            counted = res.live_entries is not None
+            with self._tel.span(
+                    "serve.resolve", rows=total, padded_to=res.padded_to,
+                    grid_steps=res.grid_steps if counted else None) as sp:
+                try:
+                    with self._tel.span("serve.resolve.wait"):
+                        jax.block_until_ready(res.distances)
+                    with self._tel.span("serve.resolve.fetch"):
+                        d = np.asarray(res.distances)
+                        labels = np.asarray(res.labels)
+                        if counted and profiling():
+                            sp.set(live_steps=self._live_steps(res, total))
+                except Exception as e:
+                    self._tel.finish_span(span)
+                    for r in chunk:
+                        r.future.set_exception(e)
+                    continue
+                t1 = self._clock()
+                self._tel.finish_span(span)  # tile wall time ~= service_s
+                off = 0
+                for r in chunk:
+                    nq = r.queries.shape[0]
+                    if self._tel.enabled:
+                        self._tel.record_duration(
+                            "serve.queue", t0 - r.t_submit, attach=False)
+                    r.future.set_result(ServeSearchResult(
+                        distances=d[off:off + nq],
+                        labels=labels[off:off + nq],
+                        k=res.k, nprobe=res.nprobe, epoch=epoch,
+                        coalesced=total, padded_to=res.padded_to,
+                        queue_s=t0 - r.t_submit, service_s=t1 - t0))
+                    off += nq
+
+    @staticmethod
+    def _live_steps(res, rows: int) -> int:
+        """Grid steps of the tile's scan that do real work: the non-empty
+        slab-table entries of its ``rows`` live query rows (a device ->
+        host copy, so only while a profiler records)."""
+        return int(np.asarray(res.live_entries)[:rows].sum())
 
     # -- lifecycle -----------------------------------------------------------
 
