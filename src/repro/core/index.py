@@ -615,9 +615,11 @@ def scan_grid_steps(q: int, t: int, block_q: int) -> int:
     ``[q, t]`` slab table: one per (query row, table entry) of each SMEM
     chunk (:func:`_split_queries`), the chunk padded to a ``block_q``
     multiple as :func:`~repro.kernels.sivf_scan.fused.sivf_fused_search_pallas`
-    pads it (both scan kernels launch the same grid). Empty (-1) entries and
-    padded rows cost a step like any other. A table row past the SMEM budget
-    (only the XLA scan runs one) counts as one chunk."""
+    pads it (both scan kernels launch the same grid). Every launched step
+    counts; a step on an empty (-1) entry skips the kernel's body and
+    fetches nothing, so the live entries (:func:`_search_impl`) are the
+    steps that do work. A table row past the SMEM budget (only the XLA
+    scan runs one) counts as one chunk."""
     rows = smem_rows(t, block_q) if 4 * t <= SMEM_TABLE_BYTES else q
     n, rows = (1, q) if q <= rows else (-(-q // rows), rows)
     bq = max(1, min(block_q, rows))
@@ -630,7 +632,7 @@ def _split_queries(kernel, per_query: tuple, table: jax.Array, rows: int
 
     One ``lax.map`` step per chunk, so each Pallas call prefetches at most
     ``rows`` table rows into SMEM; ragged tails pad with -1 table rows
-    (masked to +inf in the kernel) and are cut off again.
+    (skipped by the kernel) and are cut off again.
     """
     qn, _ = table.shape
     if qn <= rows:

@@ -21,7 +21,14 @@ This kernel is the TPU analogue of that register top-k:
   * each grid step scores one ``(query, slab)`` pair on the MXU, masks dead
     slots via the validity bitmap, and folds the ``[1, C]`` candidates into
     the running ``[1, k]`` row by k rounds of min-extraction (k is small, so
-    k passes over a VMEM-resident ``[1, k+C]`` row beat a sort).
+    k passes over a VMEM-resident ``[1, k+C]`` row beat a sort);
+  * a step on an empty (-1) table entry does no work and moves no bytes:
+    :func:`compact_table` puts each row's live entries first and points
+    every empty one at the block the step before it already holds, so the
+    pipeline issues no copy for it, and the kernel skips its body. Folding
+    an empty entry would only merge ``+inf`` / ``-1`` behind the running
+    row, which first-index tie-breaking leaves as it is, so skipping it
+    changes no distance and no label.
 
 Peak memory is ``O(Q*k + bq*D + C*D)`` instead of the unfused
 ``O(Q*T*C)`` — the ``T*C`` candidate matrix is never built.
@@ -49,6 +56,31 @@ META_ROWS = 8          # sublane tile: [n_slabs, X] metadata planes are
 #                        TPU's (8, 128) block-shape rule)
 
 
+def compact_table(table: jax.Array) -> jax.Array:
+    """``[Q, T]`` slab table -> the same live entries, laid out for the grid.
+
+    Each row's non-empty (``>= 0``) entries move to its front in their
+    order (a stable sort), so the kernel folds the same candidates in the
+    same order. Every empty entry after them holds ``-1 - s``: ``s`` is the
+    slab the grid step before it reads, which is the row's last live slab,
+    or for a row with none the last live slab of the rows above it, or
+    slab 0 at the start of the call. :func:`slab_index_maps` decodes it, so
+    a skipped step asks for the block already in VMEM and no DMA is issued.
+    """
+    q, t = table.shape
+    dead = (table < 0).astype(jnp.int32)
+    _, comp = jax.lax.sort((dead, table), dimension=1, is_stable=True,
+                           num_keys=1)
+    n = t - jnp.sum(dead, axis=1)                       # live entries, [Q]
+    last = jnp.take_along_axis(comp, jnp.maximum(n - 1, 0)[:, None],
+                               axis=1)[:, 0]
+    # the nearest row at or above each row that has a live entry
+    src = jax.lax.cummax(jnp.where(n > 0, jnp.arange(q), -1), axis=0)
+    held = jnp.where(src >= 0, last[jnp.maximum(src, 0)], 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, t), 1)
+    return jnp.where(col < n[:, None], comp, -1 - held[:, None])
+
+
 def slab_index_maps(bq: int, t: int, n_slabs: int):
     """``BlockSpec`` index maps driven by the scalar-prefetched slab table.
 
@@ -56,12 +88,14 @@ def slab_index_maps(bq: int, t: int, n_slabs: int):
     ``[1, C, X]`` payload block; ``meta_ix`` picks the ``[rows, X]`` block
     of a ``[n_slabs, X]`` metadata plane (ids, norms, bitmap) that holds
     the slab, whose row inside it is ``slab % rows`` (:func:`meta_row`).
-    Empty table entries (-1) fetch slab 0 and are masked in the kernel.
+    The table comes from :func:`compact_table`: an empty entry ``e < 0``
+    maps to slab ``-1 - e``, the block the previous grid step holds.
     """
     rows = min(META_ROWS, n_slabs)
 
     def slab(qt, qj, ti, tab):
-        return jnp.maximum(tab[(qt * bq + qj) * t + ti], 0)
+        e = tab[(qt * bq + qj) * t + ti]
+        return jnp.maximum(e, -1 - e)
 
     def payload_ix(qt, qj, ti, tab, *_):
         return (slab(qt, qj, ti, tab), 0, 0)
@@ -73,8 +107,9 @@ def slab_index_maps(bq: int, t: int, n_slabs: int):
 
 
 def meta_row(slab, rows: int):
-    """Row of ``slab`` inside the metadata block ``slab_index_maps`` chose."""
-    return pl.ds(jnp.maximum(slab, 0) % rows, 1)
+    """Row of live ``slab`` inside the metadata block ``slab_index_maps``
+    chose."""
+    return pl.ds(slab % rows, 1)
 
 
 def _unpack_bitmap(words: jax.Array, capacity: int) -> jax.Array:
@@ -160,8 +195,7 @@ def _kernel(table_ref, *refs, capacity: int, k: int, metric: str,
     bq = pl.num_programs(1)
     t = pl.num_programs(2)
     qi = pl.program_id(0) * bq + qj                     # global query row
-    slab = table_ref[qi * t + ti]                       # scalar, may be -1
-    row = meta_row(slab, ids_ref.shape[0])
+    slab = table_ref[qi * t + ti]                       # < 0: empty entry
 
     # first touch of this output block: reset the running top-k
     @pl.when((qj == 0) & (ti == 0))
@@ -169,31 +203,34 @@ def _kernel(table_ref, *refs, capacity: int, k: int, metric: str,
         outd_ref[...] = jnp.full((bq, k), jnp.inf, jnp.float32)
         outl_ref[...] = jnp.full((bq, k), -1, jnp.int32)
 
-    # -- score one (query, slab) pair on the MXU ---------------------------
-    q = q_ref[pl.ds(qj, 1), :]                          # [1, D]
-    x = data_ref[0]                                     # [C, D]
-    # HIGHEST: the MXU's default pass rounds f32 operands to bf16
-    dot = jax.lax.dot_general(
-        q.astype(jnp.float32), x.astype(jnp.float32),
-        (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)             # [1, C]
-    if metric == "l2":
-        qq = jnp.sum(q.astype(jnp.float32) ** 2)
-        d = qq - 2.0 * dot + norms_ref[row, :]
-    else:
-        d = -dot
+    @pl.when(slab >= 0)
+    def _scan():
+        row = meta_row(slab, ids_ref.shape[0])
+        # -- score one (query, slab) pair on the MXU -----------------------
+        q = q_ref[pl.ds(qj, 1), :]                      # [1, D]
+        x = data_ref[0]                                 # [C, D]
+        # HIGHEST: the MXU's default pass rounds f32 operands to bf16
+        dot = jax.lax.dot_general(
+            q.astype(jnp.float32), x.astype(jnp.float32),
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)         # [1, C]
+        if metric == "l2":
+            qq = jnp.sum(q.astype(jnp.float32) ** 2)
+            d = qq - 2.0 * dot + norms_ref[row, :]
+        else:
+            d = -dot
 
-    valid = _unpack_bitmap(bitmap_ref[row, :], capacity) & (slab >= 0)
-    if fstruct is not None:
-        # filtered-out slots fail exactly like deleted slots (+inf / -1):
-        # they can never displace a passing candidate from the top-k
-        valid &= predicate_mask(attrs_ref, consts_ref, fstruct)
-    d = jnp.where(valid, d, jnp.inf)
-    lab = jnp.where(valid, ids_ref[row, :], -1)
+        valid = _unpack_bitmap(bitmap_ref[row, :], capacity)
+        if fstruct is not None:
+            # filtered-out slots fail exactly like deleted slots (+inf /
+            # -1): they can never displace a passing candidate
+            valid &= predicate_mask(attrs_ref, consts_ref, fstruct)
+        d = jnp.where(valid, d, jnp.inf)
+        lab = jnp.where(valid, ids_ref[row, :], -1)
 
-    # -- fold candidates into the running [1, k] row -----------------------
-    fold_topk(outd_ref, outl_ref, qj, d, lab, capacity=capacity, k=k)
+        # -- fold candidates into the running [1, k] row -------------------
+        fold_topk(outd_ref, outl_ref, qj, d, lab, capacity=capacity, k=k)
 
 
 def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
@@ -208,7 +245,7 @@ def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
     """queries [Q,D], table [Q,T] -> (dists [Q,k], labels [Q,k]).
 
     Never materializes the [Q, T*C] candidate matrix; ragged Q is handled
-    by padding to a block_q multiple with -1 slab rows (masked to +inf).
+    by padding to a block_q multiple with -1 slab rows (skipped, +inf).
 
     With ``fstruct`` set (a compiled predicate structure from
     ``core.filters``), ``attrs`` ``[n_slabs, C, A]`` rides as one more
@@ -231,6 +268,7 @@ def sivf_fused_search_pallas(queries: jax.Array, table: jax.Array,
         table = jnp.concatenate(
             [table, jnp.full((pad, t), -1, table.dtype)])
     qp = qn + pad
+    table = compact_table(table)
 
     grid = (qp // bq, bq, t)
     rows, slab_ix, meta_ix = slab_index_maps(bq, t, ns)

@@ -17,8 +17,8 @@ Same shape as ``fused.py`` otherwise:
   * the grid walks ``(q_tile, q_within_tile, slab)``, the ``[bq, k]``
     output block is revisited across the inner two axes and flushed once
     per tile;
-  * deleted slots mask through the validity bitmap, empty chains (-1 slab
-    ids) score +inf / label -1.
+  * deleted slots mask through the validity bitmap; a step on an empty
+    (-1) table entry is skipped and fetches nothing (``compact_table``).
 
 TPU has no fast VMEM gather, so each subspace's lookup is a one-hot
 matmul: ``sel[C, ksub] @ adc_s[ksub]`` on the MXU. Exactly one product per
@@ -39,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.sivf_scan.fused import (
     _unpack_bitmap,
+    compact_table,
     fold_topk,
     meta_row,
     predicate_mask,
@@ -60,38 +61,41 @@ def _pq_kernel(table_ref, *refs, capacity: int, k: int, m: int,
     bq = pl.num_programs(1)
     t = pl.num_programs(2)
     qi = pl.program_id(0) * bq + qj                     # global query row
-    slab = table_ref[qi * t + ti]                       # scalar, may be -1
-    row = meta_row(slab, ids_ref.shape[0])
+    slab = table_ref[qi * t + ti]                       # < 0: empty entry
 
     @pl.when((qj == 0) & (ti == 0))
     def _init():
         outd_ref[...] = jnp.full((bq, k), jnp.inf, jnp.float32)
         outl_ref[...] = jnp.full((bq, k), -1, jnp.int32)
 
-    # -- ADC-score one (query, slab) pair ----------------------------------
-    codes = codes_ref[0].astype(jnp.int32)              # [C, m]
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (capacity, ksub), 1)
-    d = None
-    for s in range(m):                                  # ascending subspaces
-        sel = (kcol == codes[:, s][:, None]).astype(jnp.float32)  # [C, K]
-        adc_s = adc_ref[pl.ds(qj, 1), pl.ds(s * ksub, ksub)]      # [1, K]
-        # HIGHEST precision: the default MXU pass truncates f32 operands
-        # to bf16, which would round the looked-up table entry and break
-        # bit-exactness on real TPUs (interpret mode hides this)
-        term = jax.lax.dot_general(
-            adc_s, sel, (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)         # [1, C]
-        d = term if d is None else d + term
+    @pl.when(slab >= 0)
+    def _scan():
+        row = meta_row(slab, ids_ref.shape[0])
+        # -- ADC-score one (query, slab) pair ------------------------------
+        codes = codes_ref[0].astype(jnp.int32)          # [C, m]
+        kcol = jax.lax.broadcasted_iota(jnp.int32, (capacity, ksub), 1)
+        d = None
+        for s in range(m):                              # ascending subspaces
+            sel = (kcol == codes[:, s][:, None]).astype(jnp.float32)
+            adc_s = adc_ref[pl.ds(qj, 1), pl.ds(s * ksub, ksub)]  # [1, K]
+            # HIGHEST precision: the default MXU pass truncates f32
+            # operands to bf16, which would round the looked-up table
+            # entry and break bit-exactness on real TPUs (interpret mode
+            # hides this)
+            term = jax.lax.dot_general(
+                adc_s, sel, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)     # [1, C]
+            d = term if d is None else d + term
 
-    valid = _unpack_bitmap(bitmap_ref[row, :], capacity) & (slab >= 0)
-    if fstruct is not None:
-        # filtered-out slots fail exactly like deleted slots (+inf / -1)
-        valid &= predicate_mask(attrs_ref, consts_ref, fstruct)
-    d = jnp.where(valid, d, jnp.inf)
-    lab = jnp.where(valid, ids_ref[row, :], -1)
+        valid = _unpack_bitmap(bitmap_ref[row, :], capacity)
+        if fstruct is not None:
+            # filtered-out slots fail exactly like deleted slots (+inf / -1)
+            valid &= predicate_mask(attrs_ref, consts_ref, fstruct)
+        d = jnp.where(valid, d, jnp.inf)
+        lab = jnp.where(valid, ids_ref[row, :], -1)
 
-    fold_topk(outd_ref, outl_ref, qj, d, lab, capacity=capacity, k=k)
+        fold_topk(outd_ref, outl_ref, qj, d, lab, capacity=capacity, k=k)
 
 
 def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
@@ -106,7 +110,7 @@ def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
 
     ``adc`` comes from ``core.pq.adc_tables`` (already metric-shaped, so
     the kernel itself is metric-agnostic); ragged Q pads to a ``block_q``
-    multiple with -1 slab rows (masked to +inf) and zero ADC rows.
+    multiple with -1 slab rows (skipped, +inf) and zero ADC rows.
 
     ``attrs``/``fstruct``/``fconsts`` add the compiled-predicate mask
     exactly as in ``fused.sivf_fused_search_pallas``: attributes become a
@@ -128,6 +132,7 @@ def sivf_pq_fused_search_pallas(adc: jax.Array, table: jax.Array,
         table = jnp.concatenate(
             [table, jnp.full((pad, t), -1, table.dtype)])
     qp = qn + pad
+    table = compact_table(table)
 
     grid = (qp // bq, bq, t)
     rows, slab_ix, meta_ix = slab_index_maps(bq, t, ns)

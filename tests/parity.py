@@ -91,6 +91,38 @@ def churn(cfg, state, rng, steps=4, id_space=512, rows=None):
     return state, rows
 
 
+# Slab-table shapes whose empty entries the scan kernels skip, as parity
+# cases: name -> (query rows, block_q, rows made all -1, rows loaded twice
+# under new ids so that distances tie)
+TABLE_CASES = {
+    "interleaved": (5, 8, (), False),
+    "dead_rows": (6, 4, (0, 3), False),
+    "ragged": (13, 8, (12,), False),
+    "duplicates": (9, 4, (), True),
+}
+
+
+def spread_table(table, rng, chains, dead_rows=()):
+    """The same live entries in the same order, with -1 runs between them.
+
+    Each of a row's ``chains`` segments (one per probed list) doubles in
+    width and holds its live entries at random places in their order, -1
+    around them; the rows in ``dead_rows`` become all -1.
+    """
+    t = np.asarray(table)
+    q, w = t.shape
+    seg = t.reshape(q, chains, w // chains)
+    out = np.full((q, chains, 2 * seg.shape[2]), -1, t.dtype)
+    for i in range(q):
+        for j in range(chains):
+            live = seg[i, j][seg[i, j] >= 0]
+            at = np.sort(rng.choice(out.shape[2], live.size, replace=False))
+            out[i, j, at] = live
+    out = out.reshape(q, -1)
+    out[list(dead_rows)] = -1
+    return jnp.asarray(out)
+
+
 def assert_search_parity(cfg, state, rng, k, nprobe, q=5, use_tables=True,
                          block_q=8, pred=None, exact_dist=None,
                          queries=None):

@@ -3,7 +3,8 @@
 The fused Pallas kernel (kernels/sivf_scan/fused.py) must match
 ``core.index.scan_slabs_topk`` — the jnp register-top-k analogue — on
 distances AND labels, including deleted-slot masking, empty chains,
-``k > n_live`` padding, and ragged query counts (block_q padding path).
+``k > n_live`` padding, ragged query counts (block_q padding path), and
+tables whose empty entries the kernel skips without a copy.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -32,11 +33,13 @@ def load(cfg, state, rng, n, lists=None):
 
 
 def assert_fused_matches_ref(cfg, state, rng, k, nprobe, q=5, block_q=8,
-                             use_tables=True):
+                             use_tables=True, reshape=None):
     qs = jnp.asarray(rng.normal(size=(q, D)).astype(np.float32))
     lists = core.probe(state.centroids, qs, nprobe, cfg.metric)
     table = (core.gather_tables if use_tables else core.walk_chains)(
         cfg, state, lists)
+    if reshape is not None:
+        table = reshape(table)
     dr, lr = core.scan_slabs_topk(cfg, state, qs, table, k)
     df, lf = scan_ops.sivf_fused_search(
         qs, table, state.data, state.ids, state.norms, state.bitmap, k,
@@ -97,6 +100,80 @@ def test_fused_ragged_query_blocking(rng, q, block_q):
     state = load(cfg, state, rng, 150)
     assert_fused_matches_ref(cfg, state, rng, k=5, nprobe=2, q=q,
                              block_q=block_q)
+
+
+@pytest.mark.parametrize("case", sorted(parity.TABLE_CASES))
+def test_fused_parity_sparse_tables(rng, case):
+    """-1 runs inside every probed chain, all -1 rows, ragged Q and tied
+    duplicates: the skipped steps change no label and no distance."""
+    q, block_q, dead, dup = parity.TABLE_CASES[case]
+    cfg, state = make(rng)
+    vecs = rng.normal(size=(100, D)).astype(np.float32)
+    state, _, _ = parity.load_rows(cfg, state, rng, 100, vecs=vecs)
+    if dup:
+        state, _, _ = parity.load_rows(cfg, state, rng, 100, start=100,
+                                       vecs=vecs)
+    df, lf = assert_fused_matches_ref(
+        cfg, state, rng, k=7, nprobe=NL, q=q, block_q=block_q,
+        reshape=lambda t: parity.spread_table(t, rng, NL, dead))
+    assert np.isinf(df[list(dead)]).all() and (lf[list(dead)] == -1).all()
+    if dup:
+        assert (np.diff(df, axis=1) == 0).any()     # ties were resolved
+
+
+def grid_blocks(table, bq, n_slabs):
+    """Walk the kernel's grid over ``table`` ([Q, T], Q a ``bq`` multiple)
+    in its order, checking the blocks each step's index maps ask for;
+    returns the live steps per row."""
+    from repro.kernels.sivf_scan.fused import compact_table, slab_index_maps
+    tab = np.asarray(table)
+    q, t = tab.shape
+    flat = np.asarray(compact_table(jnp.asarray(tab))).reshape(-1)
+    rows, payload_ix, meta_ix = slab_index_maps(bq, t, n_slabs)
+    prev = ((0, 0, 0), (0, 0))          # what the first step's fetch reads
+    live = np.zeros(q, np.int32)
+    for qi in range(q):
+        want = tab[qi][tab[qi] >= 0]
+        for ti in range(t):
+            ix = divmod(qi, bq) + (ti, flat)
+            got = (tuple(int(v) for v in payload_ix(*ix)),
+                   tuple(int(v) for v in meta_ix(*ix)))
+            if flat[qi * t + ti] >= 0:
+                # a live step reads its own slab, in the row's order
+                s = int(want[live[qi]])
+                assert got == ((s, 0, 0), (s // rows, 0))
+                live[qi] += 1
+            else:
+                # a dead step asks for the block in hand: no copy is issued
+                assert got == prev
+            prev = got
+    return live
+
+
+def test_compacted_index_maps_skip_dead_steps(rng):
+    """The index maps over a compacted table: each live step maps to its
+    own slab, each dead step to the block of the step before it, and the
+    live steps are ``_search_impl``'s ``live_entries``."""
+    cfg, state = make(rng, n_slabs=48, max_chain=12)
+    state = load(cfg, state, rng, 300)
+    qs = jnp.asarray(rng.normal(size=(16, D)).astype(np.float32))
+    _, _, live = core.index._search_impl(cfg, state, qs, 5, 3, True, "xla",
+                                         8)
+    table = core.gather_tables(cfg, state,
+                               core.probe(state.centroids, qs, 3, cfg.metric))
+    assert (grid_blocks(table, 8, cfg.n_slabs) == np.asarray(live)).all()
+    dead = [0, 5, 6, 15]
+    want = np.asarray(live).copy()
+    want[dead] = 0
+    got = grid_blocks(parity.spread_table(table, rng, 3, dead), 8,
+                      cfg.n_slabs)
+    assert (got == want).all() and want.sum() > 0
+    for density in (0.0, 0.1, 0.6, 1.0):
+        tab = np.where(rng.random((8, 40)) < density,
+                       rng.integers(0, cfg.n_slabs, (8, 40)), -1)
+        tab[rng.integers(0, 8)] = -1
+        got = grid_blocks(tab.astype(np.int32), 4, cfg.n_slabs)
+        assert (got == (tab >= 0).sum(axis=1)).all()
 
 
 def test_fused_pointer_walk_table(rng):

@@ -159,12 +159,14 @@ pq_kernel = pytest.mark.pallas
 
 
 def assert_pq_fused_matches_ref(cfg, state, rng, k, nprobe, q=5, block_q=8,
-                                use_tables=True):
+                                use_tables=True, reshape=None):
     from repro.kernels.sivf_scan.pq_fused import sivf_pq_fused_search_pallas
     qs = jnp.asarray(clustered(rng, q))
     lists = core.probe(state.centroids, qs, nprobe, cfg.metric)
     table = (core.gather_tables if use_tables else core.walk_chains)(
         cfg, state, lists)
+    if reshape is not None:
+        table = reshape(table)
     # one materialized ADC table feeds both backends — exactly what
     # core._scan_dispatch does — so parity is structural, not rounding luck
     adc = pq.adc_tables(state.pq_codebooks, qs, cfg.metric)
@@ -233,6 +235,26 @@ def test_pq_fused_ragged_query_blocking(rng, q, block_q):
     state, _ = load(cfg, state, rng, 150)
     assert_pq_fused_matches_ref(cfg, state, rng, k=5, nprobe=2, q=q,
                                 block_q=block_q)
+
+
+@pq_kernel
+@pytest.mark.parametrize("case", sorted(parity.TABLE_CASES))
+def test_pq_fused_parity_sparse_tables(rng, case):
+    """The raw kernel's skipped-step cases (test_fused_search.py), through
+    the ADC kernel: bit-exact, ties included."""
+    q, block_q, dead, dup = parity.TABLE_CASES[case]
+    cfg, state = make(rng)
+    vecs = clustered(rng, 100)
+    state, _, _ = parity.load_rows(cfg, state, rng, 100, vecs=vecs)
+    if dup:
+        state, _, _ = parity.load_rows(cfg, state, rng, 100, start=100,
+                                       vecs=vecs)
+    df, lf = assert_pq_fused_matches_ref(
+        cfg, state, rng, k=7, nprobe=NL, q=q, block_q=block_q,
+        reshape=lambda t: parity.spread_table(t, rng, NL, dead))
+    assert np.isinf(df[list(dead)]).all() and (lf[list(dead)] == -1).all()
+    if dup:
+        assert (np.diff(df, axis=1) == 0).any()     # ties were resolved
 
 
 @pq_kernel
