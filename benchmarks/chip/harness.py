@@ -103,8 +103,7 @@ class Deployment:
         self.conf, self.traffic, self.seed = conf, traffic, int(seed)
         self.k, self.nprobe = int(conf["k"]), int(conf["nprobe"])
         self.batch = int(gen["batch_rows"])
-        self.mix = datagen.Mixture(seed, conf["dim"], gen["components"],
-                                   gen["std"], self.batch)
+        self.mix = datagen.Mixture.of(conf, seed)
         train = jnp.concatenate([self.mix.batch(b) for b in
                                  range(-(-gen["kmeans_sample"] // self.batch))
                                  ])[:gen["kmeans_sample"]]

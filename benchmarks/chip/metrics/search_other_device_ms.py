@@ -1,15 +1,11 @@
 """Device time of the search executable outside the scan kernel, per call,
-in ms: probe, slab-table gather, operand copies (the 960-lane payload
-copy among them). The executable and kernel names are those of
-``scan_kernel_ms``."""
-SEARCH_MODULE = r"^jit_search_fn\("
-KERNEL = (r'custom_call_target="tpu_custom_call"',
-          r"^%closed_call[.\d]* = .*kind=kCustom")
+in ms: probe, slab-table gather, operand copies (a relayout copy of the
+payload plane among them). The executable and the kernel are found by
+``scan_kernel.py``."""
+import scan_kernel
 
 
 def read(ctx):
-    c = ctx.conf
-    payload = f"f32[{c['n_slabs']},{c['capacity']},{c['dim']}]"
-    runs, total = ctx.trace.module_runs(SEARCH_MODULE)
-    k = ctx.trace.ops_in_module(SEARCH_MODULE, KERNEL, payload)
+    runs, total = ctx.trace.module_runs(scan_kernel.SEARCH_MODULE)
+    k = scan_kernel.kernel_s(ctx.trace, ctx.conf)
     return (total - k) / runs * 1e3 if runs and k > 0 else None

@@ -130,12 +130,12 @@ def _merge(best_d, best_x, best_s, d, xb, serial, kk):
 
 @partial(jax.jit, static_argnames=("rows", "n_gen", "kk", "precision"),
          donate_argnums=(0,))
-def _pass_block(carry, key, gcents, std, cents, queries, strict,
+def _pass_block(carry, key, gcents, std, basis, cents, queries, strict,
                 loose, first_batch, add_ep, rem_ep, q_epoch, got_serial, *,
                 rows: int, n_gen: int, kk: int, precision: str):
     """One block of ``n_gen`` generator batches against every sampled query."""
-    xb = jnp.concatenate([batch(key, gcents, first_batch + i, rows, ROWS, std)
-                          for i in range(n_gen)])
+    xb = jnp.concatenate([batch(key, gcents, first_batch + i, rows, ROWS, std,
+                                basis) for i in range(n_gen)])
     lo = first_batch * rows
     serial = lo + jnp.arange(xb.shape[0], dtype=jnp.int32)
     l1, l2, amb = route(cents, xb, precision)
@@ -275,8 +275,8 @@ def _passes(mix, cents, ledger, queries, q_epoch, k, nprobe, got_serial,
     gs = jnp.asarray(np.clip(got_serial, -1, np.iinfo(np.int32).max), jnp.int32)
     for b0 in range(0, n_batches, block_batches):
         lo, hi = b0 * rows, (b0 + block_batches) * rows
-        carry = _pass_block(carry, mix.key, mix.cents, mix.std, cents,
-                            queries, strict, loose, np.int32(b0),
+        carry = _pass_block(carry, mix.key, mix.cents, mix.std, mix.basis,
+                            cents, queries, strict, loose, np.int32(b0),
                             jnp.asarray(add_ep[lo:hi]), jnp.asarray(rem_ep[lo:hi]),
                             q_ep, gs, rows=rows, n_gen=block_batches, kk=kk,
                             precision=precision)
@@ -351,9 +351,9 @@ def compare(mix, cents, ledger: Ledger, queries, q_epoch, got_labels,
 
 
 @partial(jax.jit, static_argnames=("rows", "n_gen"))
-def _route_block(key, gcents, std, cents, first_batch, *, rows, n_gen):
-    xb = jnp.concatenate([batch(key, gcents, first_batch + i, rows, ROWS, std)
-                          for i in range(n_gen)])
+def _route_block(key, gcents, std, basis, cents, first_batch, *, rows, n_gen):
+    xb = jnp.concatenate([batch(key, gcents, first_batch + i, rows, ROWS, std,
+                                basis) for i in range(n_gen)])
     return route(cents, xb, "f32")[0]
 
 
@@ -364,8 +364,8 @@ def list_rows(mix, cents, live: np.ndarray, block_batches: int = 4
     n_batches = -(-len(live) // rows)
     counts = np.zeros(cents.shape[0], np.int64)
     for b0 in range(0, n_batches, block_batches):
-        l1 = np.asarray(_route_block(mix.key, mix.cents, mix.std, cents,
-                                     np.int32(b0), rows=rows,
+        l1 = np.asarray(_route_block(mix.key, mix.cents, mix.std, mix.basis,
+                                     cents, np.int32(b0), rows=rows,
                                      n_gen=block_batches))
         lo = b0 * rows
         m = live[lo:lo + len(l1)]

@@ -1,7 +1,11 @@
 """Rehearse the cells without a chip: memory of every executable, and list
 fill of every configuration's base set.
 
-    JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py [config ...]
+    JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py \
+        [config ...] [--seeds N ...]
+
+A config is a name under ``configs/`` or the path of a JSON file of the
+same form (a configuration being sized before it is committed).
 
 1. Compiles, for a described TPU v5e, every executable a cell runs: the
    index's insert and delete at the ingest batch, its search at each query
@@ -12,10 +16,12 @@ fill of every configuration's base set.
 2. On the CPU, makes each configuration's base rows from a seed, trains the
    benchmark's k-means and routes every row, and prints the fullest list
    against ``max_chain * capacity`` (the bound at which an insert stops with
-   ``CHAIN_OVERFLOW``).
+   ``CHAIN_OVERFLOW``), once per seed. The generator is the configuration's
+   own (``datagen.Mixture.of``): its ``std`` and, where set, ``noise_rank``.
 """
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -78,11 +84,14 @@ def compile_all(conf: dict, sh) -> dict:
         out[f"search{q}"] = mem(c)
     key = jax.eval_shape(lambda: datagen.seed_key(0))
     key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=sh)
-    gc = spec((conf["generator"]["components"], d), jnp.float32)
-    out["generator"] = mem(jax.jit(
-        datagen._take, static_argnames=("rows", "n", "stream")).lower(
+    gen = conf["generator"]
+    gc = spec((gen["components"], d), jnp.float32)
+    std = float(gen["std"])
+    basis = spec((gen["noise_rank"], d), jnp.float32) \
+        if "noise_rank" in gen else None
+    out["generator"] = mem(datagen._take.lower(
         key, gc, spec((), jnp.int32), spec((), jnp.int32), rows=b, n=b,
-        stream=0, std=0.3).compile())
+        stream=0, std=std, basis=basis).compile())
     out["kmeans"] = mem(reference.kmeans.lower(
         key, spec((conf["generator"]["kmeans_sample"], d), jnp.float32),
         n_lists=conf["n_lists"], iters=conf["generator"]["kmeans_iters"]
@@ -93,8 +102,8 @@ def compile_all(conf: dict, sh) -> dict:
     lmask = spec((s, conf["n_lists"]), jnp.bool_)
     ep = spec((nb * b,), jnp.int32)
     out["reference_block"] = mem(reference._pass_block.lower(
-        carry, key, gc, 0.3, cents, spec((s, d), jnp.float32), lmask, lmask,
-        spec((), jnp.int32), ep, ep, spec((s,), jnp.int32),
+        carry, key, gc, std, basis, cents, spec((s, d), jnp.float32), lmask,
+        lmask, spec((), jnp.int32), ep, ep, spec((s,), jnp.int32),
         spec((s, k), jnp.int32), rows=b, n_gen=nb, kk=kk,
         precision="f32").compile())
     return out
@@ -106,8 +115,7 @@ def list_fill(conf: dict, seed: int) -> dict:
     import datagen
     import reference
     gen = conf["generator"]
-    mix = datagen.Mixture(seed, conf["dim"], gen["components"], gen["std"],
-                          gen["batch_rows"])
+    mix = datagen.Mixture.of(conf, seed)
     import jax
     import jax.numpy as jnp
     train = jnp.concatenate([mix.batch(i) for i in range(
@@ -123,23 +131,34 @@ def list_fill(conf: dict, seed: int) -> dict:
             "n_slabs": conf["n_slabs"]}
 
 
+def load_conf(name: str) -> dict:
+    path = Path(name) if name.endswith(".json") else \
+        HERE / "configs" / f"{name}.json"
+    return json.loads(path.read_text())
+
+
 def main(argv) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("configs", nargs="*")
+    ap.add_argument("--seeds", type=int, nargs="+", default=[2 ** 31 + 11])
+    args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(HERE))
-    names = argv or [p.stem for p in sorted((HERE / "configs").glob("*.json"))]
+    names = args.configs or [p.stem for p in
+                             sorted((HERE / "configs").glob("*.json"))]
+    confs = {name: load_conf(name) for name in names}
     sh = described_chip()
-    for name in names:
-        conf = json.loads((HERE / "configs" / f"{name}.json").read_text())
+    for name, conf in confs.items():
         t = time.perf_counter()
         print(name, "compiled:", json.dumps(compile_all(conf, sh)),
               f"({time.perf_counter() - t:.1f} s)", flush=True)
     import jax
     jax.config.update("jax_platforms", "cpu")
-    for name in names:
-        conf = json.loads((HERE / "configs" / f"{name}.json").read_text())
-        t = time.perf_counter()
-        print(name, "list fill:", json.dumps(list_fill(conf, 2 ** 31 + 11)),
-              f"({time.perf_counter() - t:.1f} s)", flush=True)
+    for name, conf in confs.items():
+        for seed in args.seeds:
+            t = time.perf_counter()
+            print(name, "list fill:", json.dumps(list_fill(conf, seed)),
+                  f"({time.perf_counter() - t:.1f} s)", flush=True)
     return 0
 
 

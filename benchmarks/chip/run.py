@@ -5,13 +5,14 @@
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``configs/<name>.json``) and a traffic mix (``traffic/<name>.json``, read
-by ``generator.py``). The
-run makes the rows from ``--seed`` on the device, trains the coarse
-quantizer, ingests the base rows, warms every shape the window uses (all of
-that is ``setup_s``), then serves the traffic through ``ServeEngine`` for
-``--seconds``. Once the window has closed it reads the device's memory
-peak, reads the live ids back, frees the index and compares a sample of
-the answers with the plain reference (``reference.py``).
+by ``generator.py``). The run makes the rows on the device (from the
+configuration's ``dataset_seed`` where it sets one, else from ``--seed``)
+and the queries from ``--seed``, trains the coarse quantizer, ingests the
+base rows, warms every shape the window uses (all of that is ``setup_s``),
+then serves the traffic through ``ServeEngine`` for ``--seconds``. Once the
+window has closed it reads the device's memory peak, reads the live ids
+back, frees the index and compares a sample of the answers with the plain
+reference (``reference.py``).
 
 With ``--trace 0`` the result holds the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the JAX profiler and the result holds

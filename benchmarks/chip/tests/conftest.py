@@ -21,13 +21,15 @@ import pytest  # noqa: E402
 def tiny_cell(writer: bool, traffic_over=None, **conf_over):
     """The sift1m configuration and a cell's traffic at a size the CPU
     runs in seconds; every other setting as the chip runs it.
-    ``traffic_over`` replaces top-level keys of the mix's file."""
+    ``traffic_over`` replaces top-level keys of the mix's file, a
+    ``generator`` in ``conf_over`` keys of the configuration's generator."""
     import harness
     from generator import Mix
     conf = json.loads((BENCH / "configs" / "sift1m.json").read_text())
     conf.update(dim=16, base_rows=6000, n_lists=16, nprobe=4, n_slabs=1024,
                 max_chain=64, n_max=16384, sustained_qps=100)
-    conf["generator"].update(batch_rows=1024, kmeans_sample=2048)
+    conf["generator"].update(batch_rows=1024, kmeans_sample=2048,
+                             **conf_over.pop("generator", {}))
     conf.update(conf_over)
     traffic = json.loads((BENCH / "traffic" / (
         "churn_window.json" if writer else "search_open_loop.json")).read_text())

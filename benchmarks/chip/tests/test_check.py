@@ -2,20 +2,28 @@
 control (the reference at bfloat16) and the faults a cell can have fail.
 
 Each case drives a whole run of a tiny cell on the CPU, past the look for a
-chip, with the served path broken underneath where a fault is planted."""
+chip, with the served path broken underneath where a fault is planted. Each
+runs at two shapes: the sift1m configuration shrunk, and a deployment
+shaped like GIST's (a payload width that is not a multiple of the 128
+lanes, the generator's noise in a low-rank subspace)."""
 import gc
 
 import numpy as np
 import pytest
+
+SHAPES = {"dim16": {},
+          "dim200_rank16": {"dim": 200, "generator": {"noise_rank": 16}}}
+shapes = pytest.mark.parametrize("shape", list(SHAPES))
 
 
 def _failed(result):
     return {n for n, c in result["checks"].items() if c["value"] > c["limit"]}
 
 
+@shapes
 @pytest.mark.parametrize("writer", [False, True])
-def test_sound_run_is_correct(run_cell, writer, capsys):
-    result, lines = run_cell(writer=writer)
+def test_sound_run_is_correct(run_cell, writer, shape, capsys):
+    result, lines = run_cell(writer=writer, **SHAPES[shape])
     assert result["correct"], lines
     assert result["failed"] == 0
     # every end-to-end metric has its reader; the CPU has no allocator
@@ -52,12 +60,13 @@ def test_trace_run_reports_layer_metrics(run_cell):
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
-def test_control_fails():
+@shapes
+def test_control_fails(shape):
     """The reference at bfloat16 in the program's place fails the gap."""
     import control
     import harness
     from conftest import tiny_cell
-    cell = tiny_cell(False)
+    cell = tiny_cell(False, **SHAPES[shape])
     dep = harness.Deployment(cell.conf, cell.traffic, seed=31)
     dep.warm()
     w = harness.serve(dep, cell.qps_unit, 1.0, 31)
@@ -68,7 +77,8 @@ def test_control_fails():
     assert ctl["gap"] > cell.conf["limits"]["gap"] or ctl["stray"] > 0
 
 
-def test_fault_state_unchanged(run_cell, monkeypatch):
+@shapes
+def test_fault_state_unchanged(run_cell, monkeypatch, shape):
     """An add that reports success but leaves the index as it was."""
     import sivf
     orig = sivf.Index.add
@@ -90,12 +100,13 @@ def test_fault_state_unchanged(run_cell, monkeypatch):
 
     monkeypatch.setattr(sivf.Index, "add", add)
     monkeypatch.setattr(sivf.ServeEngine, "__init__", init)
-    result, _ = run_cell(writer=True)
+    result, _ = run_cell(writer=True, **SHAPES[shape])
     assert not result["correct"]
     assert "live_mismatch" in _failed(result)
 
 
-def test_fault_half_of_tile_left_out(run_cell, monkeypatch):
+@shapes
+def test_fault_half_of_tile_left_out(run_cell, monkeypatch, shape):
     """The second half of each coalesced tile gets the first half's rows."""
     import sivf
     orig = sivf.Index.search
@@ -113,12 +124,13 @@ def test_fault_half_of_tile_left_out(run_cell, monkeypatch):
                          nprobe=res.nprobe, padded_to=res.padded_to)
 
     monkeypatch.setattr(sivf.Index, "search", search)
-    result, _ = run_cell(writer=False)
+    result, _ = run_cell(writer=False, **SHAPES[shape])
     assert not result["correct"]
     assert {"gap", "stray"} & _failed(result)
 
 
-def test_fault_answer_altered(run_cell, monkeypatch):
+@shapes
+def test_fault_answer_altered(run_cell, monkeypatch, shape):
     """One label of every answer replaced where the search produces it."""
     import sivf
     orig = sivf.Index.search
@@ -131,6 +143,6 @@ def test_fault_answer_altered(run_cell, monkeypatch):
                          nprobe=res.nprobe, padded_to=res.padded_to)
 
     monkeypatch.setattr(sivf.Index, "search", search)
-    result, _ = run_cell(writer=False)
+    result, _ = run_cell(writer=False, **SHAPES[shape])
     assert not result["correct"]
     assert {"gap", "stray"} & _failed(result)

@@ -50,17 +50,20 @@ class Events:
         e = np.minimum(self.end[keep], hi)
         return Events(self.name[keep], s, e - s)
 
-    def matching(self, patterns, contains: str = "") -> np.ndarray:
+    def matching(self, patterns, contains: str | tuple = "") -> np.ndarray:
         """Mask of events whose name matches any of ``patterns`` (regular
-        expressions) and holds ``contains``."""
+        expressions) and holds ``contains``, or one of its strings where it
+        is a tuple."""
         rx = [re.compile(p) for p in ([patterns] if isinstance(patterns, str)
                                       else patterns)]
+        subs = (contains,) if isinstance(contains, str) else tuple(contains)
         memo: dict = {}
         out = np.zeros(len(self), bool)
         for i, n in enumerate(self.name):
             hit = memo.get(n)
             if hit is None:
-                hit = memo[n] = contains in n and any(r.search(n) for r in rx)
+                hit = memo[n] = any(s in n for s in subs) \
+                    and any(r.search(n) for r in rx)
             out[i] = hit
         return out
 
@@ -176,10 +179,12 @@ class Trace:
         hit = e.matching(pattern)
         return int(hit.sum()), float(np.sum(e.dur[hit])) * 1e-9
 
-    def ops_in_module(self, module: str, patterns, contains: str = "") -> float:
+    def ops_in_module(self, module: str, patterns,
+                      contains: str | tuple = "") -> float:
         """Device seconds (first device) covered by ops that match
-        ``patterns`` and hold ``contains``, and start inside a run of an
-        executable matching ``module``. Nested matches count once."""
+        ``patterns`` and hold ``contains`` (one of them, where it is a
+        tuple), and start inside a run of an executable matching
+        ``module``. Nested matches count once."""
         mods, ops = self._first(self.modules), self._first(self.ops)
         if mods is None or ops is None:
             return 0.0
